@@ -97,7 +97,7 @@ def stacked_leaf_update_stats(
     kernel backend, so it is the same figure a TPU run would launch) and the
     wall-clock of the jitted leaf update on the default backend (``ref`` on
     CPU — same trace shape, honest step timing).  The launch count is the
-    drift-gated number: it must stay 1 (the single-launch 3-d-grid
+    drift-gated number: it must stay 1 (the single-launch
     invariant); wall-clock is recorded for the trajectory but not gated
     (CI machines are too noisy for exact step-time equality).
     """

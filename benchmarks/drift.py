@@ -12,7 +12,7 @@ a small deterministic JSON-able dict:
   Structural, so it must reproduce exactly anywhere.
 * stacked — the fused stacked-leaf update on an L=24 transformer-block
   stack: the Pallas launch count (structural; gated EXACTLY at its baseline
-  of 1 — the single-launch 3-d-grid invariant) and the step wall-clock,
+  of 1 — the single-launch invariant) and the step wall-clock,
   gated within a ±25% relative band of the baseline: wide enough for CI
   machine noise, tight enough that a silent 2x slowdown (or the ~20%
   regression that once landed unnoticed) fails the job instead of merging.
@@ -250,7 +250,7 @@ def compare(
                 violations.append(
                     f"stacked.{key} changed: {cur_st[key]} vs baseline "
                     f"{base_st[key]} — the fused stacked-leaf path regressed "
-                    "(single-launch 3-d grid)"
+                    "(single launch)"
                 )
         base_us = base_st.get("us_per_step")
         cur_us = cur_st.get("us_per_step")

@@ -265,13 +265,13 @@ def thm1_sgdm_convergence() -> List[Tuple[str, float, str]]:
 
 def stacked_fused_steptime() -> List[Tuple[str, float, str]]:
     """Stacked-leaf fused update: an L=24 transformer-block stack must run as
-    ONE 3-d-grid Pallas launch (the ROADMAP "fuse the stacked-leaf loop"
+    ONE Pallas launch (the ROADMAP "fuse the stacked-leaf loop"
     item) — the row records the launch count and the SR step wall-clock."""
     s = stacked_leaf_update_stats()
     return [(
         f"stacked/L{s['L']}x{s['R']}x{s['C']}-fused-SR",
         s["us_per_step"],
-        f"pallas_launches={s['launch_count']} (single 3-d-grid launch; "
+        f"pallas_launches={s['launch_count']} (single launch; "
         f"was {s['L']} per-slice launches)",
     )]
 

@@ -47,7 +47,7 @@ from jax.sharding import Mesh, NamedSharding
 from repro.comms.config import GRAD_COMM_KEY_DOMAIN, CommsConfig
 from repro.core.quantizer import QuantConfig, QuantizedTensor, dequantize, quantize
 from repro.kernels.sr import STREAM_GRAD, tensor_uniforms
-from repro.sharding.rules import wire_spec
+from repro.sharding.rules import auto_axes, wire_spec
 
 __all__ = ["quantized_all_reduce", "reduce_grads", "grad_comm_key"]
 
@@ -143,6 +143,8 @@ def reduce_grads(
     mesh run pays).  ``key`` (from ``grad_comm_key``) enables stochastic
     rounding; without it quantized modes fall back to round-to-nearest.
     """
+    if mesh is not None:
+        mesh = auto_axes(mesh)
     g_leaves, treedef = jax.tree_util.tree_flatten(grads)
     if axes is not None:
         a_leaves = jax.tree_util.tree_leaves(axes, is_leaf=_IS_AXES_LEAF)

@@ -776,7 +776,7 @@ class FusedAdamWRoute:
     and emits a ``Replace`` update leaf.  Eligibility mirrors the kernel's
     layout contract: 4-bit B128 m, 4-bit rank-1 v, ndim>=2 param with the
     last dim a multiple of 256 (nibble + B128 tile alignment); leading dims
-    run as stacked 2-d slices of ONE 3-d-grid launch (the outer grid dim
+    run as stacked 2-d slices of ONE launch (the outer grid dim
     walks the slices — a deep layer stack costs a single ``pallas_call``,
     not L of them).  Stochastic-rounding configs are eligible — the kernel
     requantizes with in-tile counter-based Threefry noise keyed by the

@@ -8,21 +8,24 @@ params back — the precise fp32 moments never round-trip through HBM.
 
 TPU adaptation (vs the CUDA original):
   * table lookup is a branchless 16-way select tree (no per-thread binary
-    search; the 16-entry table lives in VMEM / VREGs),
+    search; the 16-entry tables and the scalars live in SMEM),
   * encoding is a midpoint compare-and-sum: idx = sum_k [n > mid_k],
-  * nibble pack/unpack are lane-local shifts on the last axis,
-  * first-moment B128 block scales are computed inside the tile (tile cols
-    are multiples of 128, so blocks never straddle tiles),
+  * nibble pack/unpack are lane shuffles, done as exact 0/1 matmuls on the
+    otherwise idle MXU (Mosaic cannot lower them as reshapes),
+  * first-moment B128 block scales are computed inside the tile (tiles span
+    whole rows, so blocks never straddle tiles),
   * second-moment rank-1 scales of the NEW v need global row/col maxes, so
     they are computed in a prepass (XLA fuses dequant+max; nothing fp32 is
     materialized in HBM) and fed to the kernel — the two-pass structure that
     replaces CUDA's atomics-based reduction.
 
-Tiles are (TR, TC) with TC a multiple of 256 so that packed code tiles
-(TC/2) and B128 scale tiles (TC/128) stay integral.
+Tiles are (TR, C): whole rows, because a block's last dim must be a multiple
+of 128 or the full dim and the B128 scale block is C/128 wide.  C is a
+multiple of 256 so packed codes (C/2) and the MXU shuffles (256 columns a
+step) stay integral; TR shrinks as C grows (``pick_tile_r``).
 
 Stacked leaves run as ONE launch: ``fused_adamw4`` takes (L, R, C) operands
-and a 3-d grid (L, R/TR, C/TC) whose outer dim walks the leading slices — no
+and a grid (L, R/TR) whose outer dim walks the leading slices — no
 per-slice Python loop, no L-unrolled jaxpr, one kernel launch per leaf.  Per
 slice the v scale is ``min(row_stat, col_stat)`` with per-slice row stats
 (L, R) and column stats (C,) shared across slices (rank-1 stats are global
@@ -31,11 +34,11 @@ operands are accepted and treated as L == 1.
 
 Stochastic rounding (``use_sr=True``) requantizes both moments with
 counter-based Threefry-2x32 noise generated *inside* the tile: the counter is
-the element's global index in its (R, C) slice, the key the slice's row of
-the (L, 2) seed input (indexed by the outer grid dim), and the stream id
-separates m from v — so the noise is a pure function of (key, element),
-independent of tiling, mesh layout, AND of whether slices launch separately
-or through the 3-d grid; it is bit-identical to the pure-jnp SR oracle in
+the element's global index in its (R, C) slice, the key the slice's two
+seed words (indexed by the outer grid dim), and the stream id separates m
+from v — so the noise is a pure function of (key, element), independent of
+tiling, mesh layout, AND of whether slices launch separately or in one
+launch; it is bit-identical to the pure-jnp SR oracle in
 ``ref.py`` (see ``sr.py``).
 """
 
@@ -47,6 +50,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.sr import (
     STREAM_M,
@@ -55,18 +59,17 @@ from repro.kernels.sr import (
     uniform_from_bits,
 )
 
-__all__ = ["fused_adamw4", "TILE_R", "TILE_C"]
+__all__ = ["fused_adamw4", "pick_tile_r", "TILE_ELEMS"]
 
-TILE_R = 128
-TILE_C = 512
+TILE_ELEMS = 64 * 1024  # elements per (TR, C) tile; TR shrinks as C grows
 _BLOCK = 128  # first-moment block size (B128)
 
 
 def _decode16(codes, table_ref):
-    """Branchless 16-way select: vals[i] = table[codes[i]]."""
+    """Branchless 16-way select: vals[i] = table[codes[i]] (table in SMEM)."""
     acc = jnp.zeros(codes.shape, jnp.float32)
     for k in range(16):
-        acc = jnp.where(codes == k, table_ref[0, k], acc)
+        acc = jnp.where(codes == k, table_ref[k], acc)
     return acc
 
 
@@ -74,9 +77,9 @@ def _encode16(n, table_ref, num_points: int):
     """Round-to-nearest codes via midpoint compare-and-sum."""
     idx = jnp.zeros(n.shape, jnp.int32)
     for k in range(num_points - 1):
-        mid = (table_ref[0, k] + table_ref[0, k + 1]) * 0.5
+        mid = (table_ref[k] + table_ref[k + 1]) * 0.5
         idx = idx + (n > mid).astype(jnp.int32)
-    return idx.astype(jnp.uint8)
+    return idx
 
 
 def _encode16_sr(n, table_ref, num_points: int, u):
@@ -89,95 +92,129 @@ def _encode16_sr(n, table_ref, num_points: int, u):
     """
     ge = jnp.zeros(n.shape, jnp.int32)
     for k in range(num_points):
-        ge = ge + (n >= table_ref[0, k]).astype(jnp.int32)
+        ge = ge + (n >= table_ref[k]).astype(jnp.int32)
     lo = jnp.clip(ge - 1, 0, num_points - 2)
     t_lo = jnp.zeros(n.shape, jnp.float32)
     t_hi = jnp.zeros(n.shape, jnp.float32)
     for k in range(num_points - 1):
-        t_lo = jnp.where(lo == k, table_ref[0, k], t_lo)
-        t_hi = jnp.where(lo == k, table_ref[0, k + 1], t_hi)
+        t_lo = jnp.where(lo == k, table_ref[k], t_lo)
+        t_hi = jnp.where(lo == k, table_ref[k + 1], t_hi)
     span = jnp.maximum(t_hi - t_lo, 1e-12)
     p_hi = jnp.clip((n - t_lo) / span, 0.0, 1.0)
-    idx = lo + (u < p_hi).astype(jnp.int32)
-    return idx.astype(jnp.uint8)
+    return lo + (u < p_hi).astype(jnp.int32)
 
 
-def _tile_uniforms(seed_ref, tile_shape, full_cols: int, stream: int):
+def _tile_uniforms(seed_ref, row0_ref, tile_shape, stream: int):
     """Per-element uniforms for this tile, counter = slice-local r * C + c.
 
     Keyed on (per-slice seed words, element index, moment stream) — the
     in-kernel twin of ``sr.element_uniforms``, evaluated tile-locally so no
-    random tensor ever touches HBM.  The counter is the element's index in
-    its own (R, C) slice (grid dims 1 and 2; the outer slice dim selects the
-    seed row instead of shifting the counter), so the bits equal what a
-    standalone per-slice launch would draw.
+    random tensor ever touches HBM.  Tiles span whole rows, so the counter is
+    the element's index in its own (R, C) slice: the tile's first row (grid
+    dim 1) plus ``row0`` — the first row of this launch's shard when the
+    rows are split across devices, else 0.  The outer slice dim (grid dim 0)
+    picks the slice's two seed words instead of shifting the counter, so the
+    bits equal what a standalone, unsharded per-slice launch would draw.
     """
+    l = pl.program_id(0)
     i = pl.program_id(1)
-    j = pl.program_id(2)
     tr, tc = tile_shape
-    rows = jax.lax.broadcasted_iota(jnp.uint32, (tr, tc), 0) + (i * tr).astype(
-        jnp.uint32
-    )
-    cols = jax.lax.broadcasted_iota(jnp.uint32, (tr, tc), 1) + (j * tc).astype(
-        jnp.uint32
-    )
-    linear = rows * jnp.uint32(full_cols) + cols
+    rows = jax.lax.broadcasted_iota(jnp.uint32, (tr, tc), 0) + (
+        i * tr + row0_ref[0]
+    ).astype(jnp.uint32)
+    cols = jax.lax.broadcasted_iota(jnp.uint32, (tr, tc), 1)
+    linear = rows * jnp.uint32(tc) + cols
     bits, _ = threefry2x32(
-        seed_ref[0, 0], seed_ref[0, 1], linear, jnp.uint32(stream)
+        seed_ref[2 * l], seed_ref[2 * l + 1], linear, jnp.uint32(stream)
     )
     return uniform_from_bits(bits)
 
 
 def _unpack(packed):
-    lo = packed & jnp.uint8(0x0F)
-    hi = (packed >> 4) & jnp.uint8(0x0F)
-    return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], -1)
+    """(TR, C/2) packed bytes -> (TR, C) int32 codes (low nibble first).
+
+    The nibble interleave (byte k -> columns 2k, 2k+1) is a lane shuffle that
+    Mosaic cannot express as a reshape, so it runs on the MXU: per 128-byte
+    chunk, ``lo @ E + hi @ O`` with 0/1 matrices placing byte k's nibbles at
+    lanes 2k and 2k+1.  Codes are <= 15, so bf16 operands with fp32
+    accumulation are exact.
+    """
+    p = packed.astype(jnp.int32)
+    lo = (p & 0x0F).astype(jnp.bfloat16)
+    hi = ((p >> 4) & 0x0F).astype(jnp.bfloat16)
+    r = jax.lax.broadcasted_iota(jnp.int32, (_BLOCK, 2 * _BLOCK), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (_BLOCK, 2 * _BLOCK), 1)
+    even = (c == 2 * r).astype(jnp.bfloat16)
+    odd = (c == 2 * r + 1).astype(jnp.bfloat16)
+    chunks = []
+    for k in range(p.shape[1] // _BLOCK):
+        sl = slice(k * _BLOCK, (k + 1) * _BLOCK)
+        out = jnp.dot(lo[:, sl], even, preferred_element_type=jnp.float32)
+        out = out + jnp.dot(hi[:, sl], odd, preferred_element_type=jnp.float32)
+        chunks.append(out)
+    return jnp.concatenate(chunks, axis=1).astype(jnp.int32)
 
 
 def _pack(codes):
-    pairs = codes.reshape(codes.shape[0], -1, 2)
-    return (pairs[..., 0] | (pairs[..., 1] << 4)).astype(jnp.uint8)
+    """(TR, C) codes -> (TR, C/2) uint8 bytes (low nibble first).
+
+    The inverse lane shuffle of ``_unpack``, also on the MXU: per 256-column
+    chunk, ``codes @ S`` with S[2k, k] = 1 and S[2k+1, k] = 16 sums each
+    nibble pair into one byte value <= 255, exact in bf16 x bf16 -> fp32.
+    """
+    c16 = codes.astype(jnp.bfloat16)
+    r = jax.lax.broadcasted_iota(jnp.int32, (2 * _BLOCK, _BLOCK), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (2 * _BLOCK, _BLOCK), 1)
+    sel = jnp.where(r == 2 * c, 1.0, jnp.where(r == 2 * c + 1, 16.0, 0.0))
+    sel = sel.astype(jnp.bfloat16)
+    chunks = [
+        jnp.dot(
+            c16[:, k * 2 * _BLOCK:(k + 1) * 2 * _BLOCK], sel,
+            preferred_element_type=jnp.float32,
+        )
+        for k in range(codes.shape[1] // (2 * _BLOCK))
+    ]
+    return jnp.concatenate(chunks, axis=1).astype(jnp.int32).astype(jnp.uint8)
 
 
 def _guard(s):
     return jnp.where(s > 0, s, jnp.ones_like(s))
 
 
-def pick_tile_r(R: int, cap: int = TILE_R) -> int:
-    """Largest divisor of R that is <= cap."""
-    for d in range(min(R, cap), 0, -1):
+def pad16(t):
+    """A table padded to the select tree's fixed 16 entries (extras unused)."""
+    t = t.astype(jnp.float32)
+    return jnp.pad(t, (0, 16 - t.shape[0]))
+
+
+def pick_tile_r(R: int, C: int, budget: int = TILE_ELEMS) -> int:
+    """Rows per (TR, C) tile: the largest multiple-of-8 divisor of R with
+    TR * C <= budget (8 at least, the fp32 sublane tile); R itself when R
+    has no multiple-of-8 divisor (a full-extent block is always legal)."""
+    cap = max(8, budget // C)
+    for d in range(min(R, cap) // 8 * 8, 0, -8):
         if R % d == 0:
             return d
-    return 1
-
-
-def pick_tile_c(C: int, cap: int = TILE_C) -> int:
-    """Largest multiple-of-256 divisor of C that is <= cap (C % 256 == 0)."""
-    best = 256
-    d = 256
-    while d <= min(C, cap):
-        if C % d == 0:
-            best = d
-        d += 256
-    return best
+    return R
 
 
 def _kernel(
-    # inputs
+    # SMEM inputs
+    scalars_ref, m_table_ref, v_table_ref, seed_ref, row0_ref,
+    # VMEM inputs
     w_ref, g_ref, m_packed_ref, m_scale_ref, v_packed_ref,
     vr_ref, vc_ref, vr_new_ref, vc_new_ref,
-    scalars_ref, m_table_ref, v_table_ref, seed_ref,
     # outputs
     w_out_ref, m_packed_out_ref, m_scale_out_ref, v_packed_out_ref,
-    *, m_points: int, v_points: int, full_cols: int, use_sr: bool,
+    *, m_points: int, v_points: int, use_sr: bool,
 ):
-    lr = scalars_ref[0, 0]
-    b1 = scalars_ref[0, 1]
-    b2 = scalars_ref[0, 2]
-    eps = scalars_ref[0, 3]
-    wd = scalars_ref[0, 4]
-    bc1 = scalars_ref[0, 5]
-    bc2 = scalars_ref[0, 6]
+    lr = scalars_ref[0]
+    b1 = scalars_ref[1]
+    b2 = scalars_ref[2]
+    eps = scalars_ref[3]
+    wd = scalars_ref[4]
+    bc1 = scalars_ref[5]
+    bc2 = scalars_ref[6]
 
     # Tensor blocks carry a leading slice dim of extent 1 (the outer grid
     # dim selects which slice); [0] views them as the 2-d tile.
@@ -186,14 +223,11 @@ def _kernel(
     tr, tc = w.shape
 
     # ---- decompress (Alg. 1 line 3) ----------------------------------
-    m_codes = _unpack(m_packed_ref[0])
-    m_vals = _decode16(m_codes, m_table_ref)
-    m_scale = m_scale_ref[0]  # (TR, TC/128)
-    m = m_vals * jnp.repeat(m_scale, _BLOCK, axis=1)
+    m_vals = _decode16(_unpack(m_packed_ref[0]), m_table_ref)
+    m = m_vals * jnp.repeat(m_scale_ref[0], _BLOCK, axis=1)  # (TR, C/128)
 
-    v_codes = _unpack(v_packed_ref[0])
-    v_vals = _decode16(v_codes, v_table_ref)
-    v_scale = _guard(jnp.minimum(vr_ref[0], vc_ref[...]))  # (TR,1)x(1,TC)
+    v_vals = _decode16(_unpack(v_packed_ref[0]), v_table_ref)
+    v_scale = _guard(jnp.minimum(vr_ref[0], vc_ref[...]))  # (TR,1)x(1,C)
     v = v_vals * v_scale
 
     # ---- inner optimizer A: AdamW (Eq. 1) -----------------------------
@@ -204,11 +238,11 @@ def _kernel(
 
     # ---- compress (Alg. 1 line 5) -------------------------------------
     m_blocks = m_new.reshape(tr, tc // _BLOCK, _BLOCK)
-    m_scale_new = _guard(jnp.max(jnp.abs(m_blocks), axis=-1))  # (TR, TC/128)
+    m_scale_new = _guard(jnp.max(jnp.abs(m_blocks), axis=-1))  # (TR, C/128)
     m_scale_out_ref[0] = m_scale_new
-    m_n = (m_blocks / m_scale_new[..., None]).reshape(tr, tc)
+    m_n = m_new / jnp.repeat(m_scale_new, _BLOCK, axis=1)
     if use_sr:
-        u_m = _tile_uniforms(seed_ref, (tr, tc), full_cols, STREAM_M)
+        u_m = _tile_uniforms(seed_ref, row0_ref, (tr, tc), STREAM_M)
         m_codes = _encode16_sr(m_n, m_table_ref, m_points, u_m)
     else:
         m_codes = _encode16(m_n, m_table_ref, m_points)
@@ -217,7 +251,7 @@ def _kernel(
     v_scale_new = _guard(jnp.minimum(vr_new_ref[0], vc_new_ref[...]))
     v_n = v_new / v_scale_new
     if use_sr:
-        u_v = _tile_uniforms(seed_ref, (tr, tc), full_cols, STREAM_V)
+        u_v = _tile_uniforms(seed_ref, row0_ref, (tr, tc), STREAM_V)
         v_codes = _encode16_sr(v_n, v_table_ref, v_points, u_v)
     else:
         v_codes = _encode16(v_n, v_table_ref, v_points)
@@ -226,9 +260,7 @@ def _kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "b1", "b2", "eps", "weight_decay", "interpret", "tile_r", "tile_c", "use_sr",
-    ),
+    static_argnames=("b1", "b2", "eps", "weight_decay", "interpret", "tile_r", "use_sr"),
 )
 def fused_adamw4(
     w: jnp.ndarray,          # (L, R, C) — or (R, C), treated as L == 1
@@ -246,27 +278,31 @@ def fused_adamw4(
     bc1: jnp.ndarray,        # 1 - b1^t
     bc2: jnp.ndarray,        # 1 - b2^t
     sr_seed: Optional[jnp.ndarray] = None,  # (L, 2) uint32 per-slice key rows
+    row0: Optional[jnp.ndarray] = None,     # int32: first row of this shard
     *,
     b1: float,
     b2: float,
     eps: float,
     weight_decay: float,
     interpret: bool = False,
-    tile_r: int = TILE_R,
-    tile_c: int = TILE_C,
+    tile_r: Optional[int] = None,
     use_sr: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Run the fused update as ONE 3-d-grid launch over all stacked slices.
+    """Run the fused update as ONE 2-d-grid launch over all stacked slices.
 
-    The grid is (L, R/TR, C/TC); the outer dim walks the leading slices and
-    selects each slice's row-stat block and SR seed row.  Because the SR
-    counter stays slice-local, outputs are bit-identical to launching the 2-d
-    kernel once per slice.  2-d operands are accepted (L == 1, stats ``(R,)``
-    / seed ``(2,)``) and return 2-d outputs.
+    The grid is (L, R/TR) over whole-row (TR, C) tiles; the outer dim walks
+    the leading slices and selects each slice's row-stat block and SR seed
+    words.  Because the SR counter stays slice-local, outputs are
+    bit-identical to launching the 2-d kernel once per slice.  2-d operands
+    are accepted (L == 1, stats ``(R,)`` / seed ``(2,)``) and return 2-d
+    outputs.  ``tile_r`` overrides the row count per tile (``pick_tile_r``
+    by default); it must divide R and be a multiple of 8 or R itself.
 
     ``use_sr=True`` requantizes stochastically with in-tile Threefry noise
     keyed by ``sr_seed`` (required in that case); ``use_sr=False`` is the
-    bit-exact round-to-nearest path.
+    bit-exact round-to-nearest path.  ``row0`` (default 0) is the global
+    index of the operands' first row when they are one device's row shard
+    of larger slices, so the SR counters stay those of the whole slice.
 
     Returns (w_new, m_packed_new, m_scale_new, v_packed_new).
     """
@@ -275,15 +311,8 @@ def fused_adamw4(
         (R, C), L = w.shape, 1
     else:
         L, R, C = w.shape
-    tr = pick_tile_r(R, tile_r)
-    tc = pick_tile_c(C, tile_c)
-    assert R % tr == 0 and C % tc == 0 and tc % 256 == 0, (R, C, tr, tc)
-    grid = (L, R // tr, C // tc)
-
-    # Pad tables to 16 (select tree is fixed-width); extra entries unused.
-    def pad16(t):
-        t = t.astype(jnp.float32)
-        return jnp.pad(t, (0, 16 - t.shape[0])).reshape(1, 16)
+    tr = pick_tile_r(R, C) if tile_r is None else tile_r
+    assert R % tr == 0 and (tr % 8 == 0 or tr == R) and C % 256 == 0, (R, C, tr)
 
     m_points = int(m_table.shape[0])
     v_points = int(v_table.shape[0])
@@ -291,11 +320,10 @@ def fused_adamw4(
     if use_sr and sr_seed is None:
         raise ValueError("fused_adamw4(use_sr=True) requires sr_seed")
     seed = (
-        jnp.zeros((L, 2), jnp.uint32)
+        jnp.zeros((2 * L,), jnp.uint32)
         if sr_seed is None
-        else jnp.asarray(sr_seed, jnp.uint32).reshape(L, 2)
+        else jnp.asarray(sr_seed, jnp.uint32).reshape(2 * L)
     )
-
     scalars = jnp.stack(
         [
             jnp.asarray(lr, jnp.float32),
@@ -305,15 +333,13 @@ def fused_adamw4(
             jnp.float32(weight_decay),
             jnp.asarray(bc1, jnp.float32),
             jnp.asarray(bc2, jnp.float32),
-            jnp.float32(0.0),
         ]
-    ).reshape(1, 8)
+    )
 
-    full = lambda shape: pl.BlockSpec(shape, lambda l, i, j: (0, 0))
-    row = pl.BlockSpec((1, tr, 1), lambda l, i, j: (l, i, 0))
-    col = lambda blk: pl.BlockSpec((1, blk), lambda l, i, j: (0, j))
-    tile = lambda c: pl.BlockSpec((1, tr, c), lambda l, i, j: (l, i, j))
-    seed_row = pl.BlockSpec((1, 2), lambda l, i, j: (l, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tile = lambda c: pl.BlockSpec((1, tr, c), lambda l, i: (l, i, 0))
+    row = tile(1)
+    col = pl.BlockSpec((1, C), lambda l, i: (0, 0))
 
     out_shapes = (
         jax.ShapeDtypeStruct((L, R, C), w.dtype),
@@ -323,35 +349,44 @@ def fused_adamw4(
     )
 
     kernel = functools.partial(
-        _kernel, m_points=m_points, v_points=v_points, full_cols=C, use_sr=use_sr
+        _kernel, m_points=m_points, v_points=v_points, use_sr=use_sr
     )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(L, R // tr),
         in_specs=[
-            tile(tc),                 # w
-            tile(tc),                 # g
-            tile(tc // 2),            # m_packed
-            tile(tc // _BLOCK),       # m_scale
-            tile(tc // 2),            # v_packed
+            smem,                     # scalars (7,)
+            smem,                     # m_table (16,)
+            smem,                     # v_table (16,)
+            smem,                     # SR seed words (2L,): slice l at 2l, 2l+1
+            smem,                     # row0 (1,)
+            tile(C),                  # w
+            tile(C),                  # g
+            tile(C // 2),             # m_packed
+            tile(C // _BLOCK),        # m_scale
+            tile(C // 2),             # v_packed
             row,                      # v_r (L,R,1)
-            col(tc),                  # v_c (1,C)
+            col,                      # v_c (1,C)
             row,                      # v_r_new
-            col(tc),                  # v_c_new
-            full((1, 8)),             # scalars
-            full((1, 16)),            # m_table
-            full((1, 16)),            # v_table
-            seed_row,                 # SR seed rows (one (2,) key per slice)
+            col,                      # v_c_new
         ],
         out_specs=[
-            tile(tc),                 # w_new
-            tile(tc // 2),            # m_packed_new
-            tile(tc // _BLOCK),       # m_scale_new
-            tile(tc // 2),            # v_packed_new
+            tile(C),                  # w_new
+            tile(C // 2),             # m_packed_new
+            tile(C // _BLOCK),        # m_scale_new
+            tile(C // 2),             # v_packed_new
         ],
         out_shape=out_shapes,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
         interpret=interpret,
     )(
+        scalars,
+        pad16(m_table),
+        pad16(v_table),
+        seed,
+        jnp.zeros((1,), jnp.int32) if row0 is None else jnp.reshape(row0, (1,)).astype(jnp.int32),
         w.reshape(L, R, C),
         g.reshape(L, R, C),
         m_packed.reshape(L, R, C // 2),
@@ -361,10 +396,6 @@ def fused_adamw4(
         v_c.reshape(1, C),
         v_r_new.reshape(L, R, 1),
         v_c_new.reshape(1, C),
-        scalars,
-        pad16(m_table),
-        pad16(v_table),
-        seed,
     )
     if squeeze:
         out = tuple(o.reshape(o.shape[1:]) for o in out)

@@ -8,7 +8,7 @@ elementwise dequant->AdamW->requant in ONE Pallas launch.
 
 Leaves may have stacked leading dims (the model stores per-layer-group
 tensors ``(L, d_in, d_out)``): the leaf is viewed as L 2-d slices, all
-updated by a single ``pallas_call`` with a 3-d grid ``(L, R/TR, C/TC)`` whose
+updated by a single ``pallas_call`` with a grid ``(L, R/TR)`` whose
 outer dim walks the slices — no per-slice Python loop, so a 24-deep layer
 stack costs one launch and traces O(1) jaxpr equations (test-enforced in
 ``tests/test_kernel_fusion.py``).  The rank-1 v scales stay *global* per-dim
@@ -23,35 +23,39 @@ single vmapped ``fold_in(leaf_key, slice index)``; the resulting ``(L, 2)``
 seed rows feed the kernel's outer grid dim.  The kernel (and the reference
 oracle) expand each row to per-element Threefry noise counter-keyed on the
 element's slice-local index, so the noise is independent of tiling, mesh
-layout, and launch batching, and identical across backends — the 3-d-grid
+layout, and launch batching, and identical across backends — the single
 launch is bit-identical to the historical per-slice launches.
 
-Backend selection: on TPU the kernel runs compiled; elsewhere it runs in
-``interpret=True`` mode (Python emulation — correct but slow), unless
-``REPRO_KERNEL_BACKEND=ref`` routes to the pure-jnp reference instead
-(the default off-TPU — fast on CPU, bit-identical to the kernel).  The ref
-path vmaps the per-slice oracle over the leading dim, so it also traces O(1)
-equations regardless of L.
+Backend selection (``kernel_backend``): on TPU the kernel runs compiled;
+elsewhere the pure-jnp reference runs (fast on CPU, bit-identical to the
+kernel's interpret mode), and ``REPRO_KERNEL_BACKEND=interpret`` runs the
+kernel body emulated instead.  The ref path vmaps the per-slice oracle over
+the leading dim, so it also traces O(1) equations regardless of L.  On a
+mesh the kernel launches under ``shard_map`` (``_launch``).
 """
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.extend import core as jex_core
+from jax.sharding import PartitionSpec as P
 
 from repro.core.quantizer import QuantizedTensor
 from repro.kernels import ref
 from repro.kernels.adamw4bit import fused_adamw4
 from repro.kernels.sr import key_rows
+from repro.sharding.context import ctx_mesh
 
 __all__ = [
     "fused_adamw4_leaf",
     "kernel_backend",
     "count_pallas_calls",
+    "pallas_calls",
     "jaxpr_eqn_count",
 ]
 
@@ -60,7 +64,10 @@ _BLOCK = 128
 
 def kernel_backend() -> str:
     """'tpu' -> compiled pallas; 'interpret' -> pallas interpret mode;
-    'ref' -> pure-jnp oracle (fast on CPU)."""
+    'ref' -> pure-jnp oracle (fast on CPU).
+
+    Decided by the platform alone — 'tpu' on a TPU backend, 'ref' elsewhere
+    — unless ``REPRO_KERNEL_BACKEND`` names a route explicitly."""
     override = os.environ.get("REPRO_KERNEL_BACKEND")
     if override:
         return override
@@ -81,22 +88,31 @@ def _sub_jaxprs(eqn):
                 yield x
 
 
-def count_pallas_calls(jaxpr) -> int:
-    """Number of ``pallas_call`` equations anywhere in ``jaxpr`` (recursive).
+def pallas_calls(jaxpr) -> list:
+    """Every ``pallas_call`` equation anywhere in ``jaxpr`` (recursive).
 
-    The launch-count invariant's measuring stick: an ndim>=3 leaf through
-    ``fused_adamw4_leaf`` must trace exactly ONE (CI trace-size gate).
-    Accepts a ``Jaxpr`` or ``ClosedJaxpr``.
+    Accepts a ``Jaxpr`` or ``ClosedJaxpr``.  A fused-AdamW equation's first
+    output is the updated param, so ``eqn.outvars[0].aval.size`` is the
+    number of params that launch updates.
     """
     if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
-    n = 0
+    found = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            n += 1
+            found.append(eqn)
         for sub in _sub_jaxprs(eqn):
-            n += count_pallas_calls(sub)
-    return n
+            found.extend(pallas_calls(sub))
+    return found
+
+
+def count_pallas_calls(jaxpr) -> int:
+    """Number of ``pallas_call`` equations anywhere in ``jaxpr``.
+
+    The launch-count invariant's measuring stick: an ndim>=3 leaf through
+    ``fused_adamw4_leaf`` must trace exactly ONE (CI trace-size gate).
+    """
+    return len(pallas_calls(jaxpr))
 
 
 def jaxpr_eqn_count(jaxpr) -> int:
@@ -144,6 +160,52 @@ def _rank1_new_stats(v_new: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
     )
 
 
+def _launch(kernel, mesh, sliced, shared):
+    """Run ``kernel`` on the whole leaf, or once per device on a mesh.
+
+    A Mosaic kernel cannot be partitioned by XLA, so inside a sharding
+    context (a train step on a mesh) the launch runs under ``shard_map``:
+    each device updates a contiguous share of the rows of every slice
+    (``row0`` keeps the SR counters those of the whole slice), or of the
+    slices when the rows do not split into multiples of 8, or — when
+    neither divides — the whole leaf, redundantly.  XLA moves the operands
+    into that layout and back; the results are bit-identical to one
+    unsharded launch.  ``sliced`` operands lead with the (L, R) dims
+    (``seed_rows`` with L only, or None under RTN); ``shared`` ones are
+    whole on every device.
+    """
+
+    def run(sliced, shared, row0=None):
+        p3, g3, m_packed, m_scale, v_packed, v_r, v_r_new, seed_rows = sliced
+        v_c, v_c_new, m_table, v_table, lr, bc1, bc2 = shared
+        return kernel(
+            p3, g3, m_packed, m_scale, v_packed, v_r, v_c, v_r_new, v_c_new,
+            m_table, v_table, lr, bc1, bc2, seed_rows, row0,
+        )
+
+    if mesh is None or mesh.size == 1:
+        return run(sliced, shared)
+    L, R = sliced[0].shape[:2]
+    n, names = mesh.size, tuple(mesh.axis_names)
+    split_rows = R % (8 * n) == 0
+    if split_rows:
+        rows, seeds = P(None, names), P()
+    elif L % n == 0:
+        rows, seeds = P(names), P(names)
+    else:
+        rows, seeds = P(), P()
+
+    def body(sliced, shared):
+        row0 = jax.lax.axis_index(names) * (R // n) if split_rows else None
+        return run(sliced, shared, row0)
+
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=((rows,) * 7 + (seeds,), P()),
+        out_specs=(rows,) * 4, check_vma=False,
+    )(sliced, shared)
+
+
 def fused_adamw4_leaf(
     p: jnp.ndarray,
     g: jnp.ndarray,
@@ -157,12 +219,15 @@ def fused_adamw4_leaf(
     bc1: jnp.ndarray,
     bc2: jnp.ndarray,
     key: Optional[jax.Array] = None,
+    backend: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, QuantizedTensor, QuantizedTensor]:
     """One fused-kernel AdamW step for an ndim>=2 leaf with 4-bit m (B128)
     and 4-bit v (rank-1) — one Pallas launch regardless of stacked leading
     dims.  ``key`` activates in-kernel stochastic rounding when the configs
     request it (caller guards eligibility; no key => RTN, mirroring
-    ``quantize()``'s fallback)."""
+    ``quantize()``'s fallback).  ``backend`` (default ``kernel_backend()``)
+    names the route explicitly — a parity check runs ``"tpu"`` against
+    ``"ref"`` on the same chip."""
     shape = p.shape
     R, C = shape[-2], shape[-1]
     L = p.size // (R * C)
@@ -198,7 +263,7 @@ def fused_adamw4_leaf(
         else None
     )
 
-    backend = kernel_backend()
+    backend = backend or kernel_backend()
     if backend == "ref":
         # vmap the per-slice oracle: O(1) trace regardless of L.
         if use_sr:
@@ -224,13 +289,15 @@ def fused_adamw4_leaf(
                 p3, g3, m_packed, m_scale, v_packed, v_r, v_r_new
             )
     else:
-        # One 3-d-grid pallas_call covers every slice.
-        w3, mp3, ms3, vp3 = fused_adamw4(
-            p3, g3, m_packed, m_scale, v_packed,
-            v_r, v_c, v_r_new, v_c_new,
-            m_table, v_table, lr, bc1, bc2, seed_rows,
-            b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
+        # One pallas_call covers every slice.
+        kernel = functools.partial(
+            fused_adamw4, b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
             interpret=(backend != "tpu"), use_sr=use_sr,
+        )
+        w3, mp3, ms3, vp3 = _launch(
+            kernel, ctx_mesh(),
+            (p3, g3, m_packed, m_scale, v_packed, v_r, v_r_new, seed_rows),
+            (v_c, v_c_new, m_table, v_table, lr, bc1, bc2),
         )
 
     w_new = w3.reshape(shape).astype(p.dtype)

@@ -10,7 +10,7 @@ Every function here is vmap-safe (shape-generic jnp ops, no data-dependent
 Python): ``ops.fused_adamw4_leaf``'s ref backend vmaps
 ``fused_adamw4_reference`` / ``fused_adamw4_sr_reference`` over the leading
 slice dim of stacked leaves, tracing O(1) equations regardless of depth —
-the oracle twin of the kernel's single 3-d-grid launch.  Keep new helpers
+the oracle twin of the kernel's single launch.  Keep new helpers
 free of per-call Python loops over array contents for the same reason.
 """
 
@@ -20,6 +20,7 @@ from typing import Tuple
 
 import jax.numpy as jnp
 
+from repro.core.mappings import lookup
 from repro.kernels.sr import STREAM_M, STREAM_V, element_uniforms
 
 __all__ = [
@@ -48,7 +49,7 @@ def pack_codes(codes: jnp.ndarray) -> jnp.ndarray:
 
 
 def decode_table(codes: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
-    return jnp.take(table, codes.astype(jnp.int32), axis=0)
+    return lookup(table, codes)
 
 
 def encode_table(n: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
@@ -67,8 +68,8 @@ def encode_table_stochastic_bits(
     """
     k = table.shape[0]
     lo = jnp.clip(jnp.sum(n[..., None] >= table, axis=-1) - 1, 0, k - 2)
-    t_lo = jnp.take(table, lo, axis=0)
-    t_hi = jnp.take(table, lo + 1, axis=0)
+    t_lo = lookup(table, lo)
+    t_hi = lookup(table, lo + 1)
     span = jnp.maximum(t_hi - t_lo, 1e-12)
     p_hi = jnp.clip((n - t_lo) / span, 0.0, 1.0)
     idx = lo + (u < p_hi).astype(lo.dtype)

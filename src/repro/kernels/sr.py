@@ -93,7 +93,7 @@ def threefry2x32(k0, k1, c0, c1) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 def uniform_from_bits(bits: jnp.ndarray) -> jnp.ndarray:
     """uint32 -> fp32 uniform in [0, 1) using the top 24 bits (exact in fp32)."""
-    return jax.lax.shift_right_logical(bits, jnp.uint32(8)).astype(jnp.float32) * (
+    return jax.lax.shift_right_logical(bits, jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32) * (
         1.0 / (1 << 24)
     )
 
@@ -112,7 +112,7 @@ def key_rows(keys: jax.Array) -> jnp.ndarray:
     """(L,)-batched PRNG keys -> (L, 2) uint32 seed rows.
 
     The batched twin of ``key_words`` (same last-two-words layout per key), in
-    the shape the 3-d-grid fused kernel consumes: row ``l`` seeds slice ``l``.
+    the shape the fused kernel consumes: row ``l`` seeds slice ``l``.
     Accepts typed keys (e.g. from a vmapped ``fold_in``) or raw uint32
     ``(L, 2)`` layouts.
     """

@@ -38,7 +38,6 @@ from repro.launch.specs import decode_cache_len, input_specs
 from repro.models import ModelConfig, decode_step, init_model, loss_fn, prefill
 from repro.roofline.analysis import (
     collective_bytes_from_hlo,
-    cost_analysis_dict,
     model_flops,
     roofline_terms,
 )
@@ -172,7 +171,7 @@ def lower_cell(arch: str, shape_name: str, mesh_kind: str, opt_name: str = "adam
 
     mem = compiled.memory_analysis()
     print(mem)  # proves it fits
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     print({k: cost[k] for k in ("flops", "bytes accessed") if k in cost})
     hlo = compiled.as_text()
     coll = collective_bytes_from_hlo(hlo)

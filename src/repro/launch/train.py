@@ -1,8 +1,9 @@
 """Training CLI: --arch <id> [--reduced] --steps N.
 
-Full configs are intended for the TPU meshes (use dryrun.py to validate the
-distribution); --reduced runs the same code path at CPU scale end-to-end
-(data pipeline -> sharded step -> 4-bit optimizer -> checkpoints).
+Full configs run on TPU chips (``chip_smoke.py`` drives this module's
+functions at a published width; dryrun.py compiles the production meshes);
+--reduced runs the same code path at CPU scale end-to-end (data pipeline ->
+sharded step -> 4-bit optimizer -> checkpoints).
 
     PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --reduced --steps 30
 
@@ -17,7 +18,7 @@ Production-path flags:
                                SR keyed off the --sr-seed stream (unbiased
                                transport, bit-reproducible across resume);
                                replaces the removed grad_dtype plumbing
-  --mesh DxM                   run on a (data=D, model=M) host-device mesh via
+  --mesh DxM                   run on a (data=D, model=M) device mesh via
                                jit_train_step with explicit shardings
   --ckpt-dir PATH              resume is elastic: the restore target is built
                                abstractly (jax.eval_shape over
@@ -48,6 +49,7 @@ from repro.core.optimizers import (
     state_nbytes,
 )
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.train.checkpoint import CheckpointManager
@@ -85,6 +87,12 @@ def _uses_stochastic_rounding(opt_state) -> bool:
     )
 
 
+def _build_state(cfg, optimizer, key, captured):
+    params, axes = init_model(jax.random.PRNGKey(0), cfg)
+    captured["axes"] = axes
+    return make_train_state(params, optimizer, key=key)
+
+
 def abstract_train_state(cfg, optimizer, key=None):
     """(abstract TrainState, axes) without allocating a single param.
 
@@ -96,14 +104,77 @@ def abstract_train_state(cfg, optimizer, key=None):
     first could be dropped.
     """
     captured = {}
-
-    def build():
-        params, axes = init_model(jax.random.PRNGKey(0), cfg)
-        captured["axes"] = axes
-        return make_train_state(params, optimizer, key=key)
-
-    state_s = jax.eval_shape(build)
+    state_s = jax.eval_shape(lambda: _build_state(cfg, optimizer, key, captured))
     return state_s, captured["axes"]
+
+
+def init_or_restore(cfg, optimizer, *, key=None, mesh=None, mgr=None):
+    """(state, axes, start step) for a run.
+
+    Resumes from ``mgr``'s newest COMMIT-complete step (a save killed
+    mid-write is skipped) when there is one, else initializes.  Either way
+    the state is built straight into its final layout: on a mesh every leaf
+    lands in its ZeRO shard (``train_state_shardings``), so no device ever
+    holds the whole model — the restore is elastic onto the current mesh, and
+    a fresh init runs as one jitted program whose outputs are sharded.
+    """
+    target, axes = abstract_train_state(cfg, optimizer, key=key)
+    shardings = (
+        train_state_shardings(target, axes, mesh) if mesh is not None else None
+    )
+    start = (mgr.latest_step() or 0) if mgr else 0
+    if start:
+        state, _ = mgr.restore(target, shardings=shardings)
+    else:
+        state = jax.jit(
+            lambda: _build_state(cfg, optimizer, key, {}), out_shardings=shardings
+        )()
+    return state, axes, start
+
+
+def compile_step(cfg, optimizer, state, axes, sample, *, mesh=None, comms=None):
+    """The train step compiled ahead of time for ``state`` and ``sample``.
+
+    On a mesh: ``jit_train_step`` with explicit ZeRO shardings; otherwise a
+    single-device jit.  Both donate the state.  The returned executable is
+    what the loop runs, so its ``as_text()`` / ``memory_analysis()`` describe
+    the program that trains.
+    """
+    if mesh is not None:
+        step = jit_train_step(
+            build_train_step(cfg, optimizer, mesh, axes, zero=True, comms=comms),
+            state, sample, axes, mesh,
+        )
+    else:
+        step = jax.jit(
+            build_train_step(cfg, optimizer, comms=comms), donate_argnums=(0,)
+        )
+    return step.lower(state, sample).compile()
+
+
+def run_steps(step, state, data, start, steps, *, mgr=None, ckpt_every=25,
+              log_every=5):
+    """Train from ``start`` to ``steps``; returns (state, history).
+
+    ``history`` holds one ``(step, loss, seconds)`` per step, where seconds
+    is host wall time from dispatch until the new state is ready on device
+    (``block_until_ready``), the batch's host-side creation excluded.
+    """
+    history = []
+    for t in range(start, steps):
+        batch = {k: jnp.asarray(v) for k, v in data.batch_at(t).items()}
+        t0 = time.perf_counter()
+        state, metrics = step(state, batch)
+        jax.block_until_ready((state, metrics))
+        dt = time.perf_counter() - t0
+        history.append((t, float(metrics["loss"]), dt))
+        if mgr and (t + 1) % ckpt_every == 0:
+            mgr.save(t + 1, state)
+        if log_every and t % log_every == 0:
+            print(f"step {t:4d} loss {history[-1][1]:.4f} ({dt * 1e3:.0f} ms)")
+    if mgr:
+        mgr.wait()
+    return state, history
 
 
 def main():
@@ -143,8 +214,9 @@ def main():
                          "periodic archival anchor")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
-    if not args.reduced:
+    if not args.reduced and jax.default_backend() == "cpu":
         print("note: full config on CPU — expect long compiles; "
               "use --reduced or launch/dryrun.py for the mesh path")
     if cfg.input_mode == "embeds" or cfg.family == "encdec":
@@ -180,20 +252,9 @@ def main():
         if args.ckpt_dir
         else None
     )
-    # newest COMMIT-complete step: a save killed mid-write is skipped
-    start = (mgr.latest_step() or 0) if mgr else 0
-
+    state, axes, start = init_or_restore(cfg, opt, key=sr_key, mesh=mesh, mgr=mgr)
     if start:
-        # Elastic resume: abstract target + shardings for the current mesh.
-        target, axes = abstract_train_state(cfg, opt, key=sr_key)
-        shardings = (
-            train_state_shardings(target, axes, mesh) if mesh is not None else None
-        )
-        state, _ = mgr.restore(target, shardings=shardings)
         print(f"resumed from step {start}")
-    else:
-        params, axes = init_model(jax.random.PRNGKey(0), cfg)
-        state = make_train_state(params, opt, key=sr_key)
     print(f"arch={cfg.name} optimizer={opt.name} "
           f"state_bytes={state_nbytes(state.opt_state):,}")
 
@@ -214,28 +275,10 @@ def main():
               "transport falls back to biased round-to-nearest")
 
     data = SyntheticLM(DataConfig(cfg.vocab_size, args.seq, args.batch))
-    if mesh is not None:
-        sample = {k: jnp.asarray(v) for k, v in data.batch_at(start).items()}
-        step_fn = jit_train_step(
-            build_train_step(cfg, opt, mesh, axes, zero=True, comms=comms),
-            state, sample, axes, mesh,
-        )
-    else:
-        step_fn = jax.jit(
-            build_train_step(cfg, opt, comms=comms), donate_argnums=(0,)
-        )
-
-    for t in range(start, args.steps):
-        batch = {k: jnp.asarray(v) for k, v in data.batch_at(t).items()}
-        t0 = time.perf_counter()
-        state, metrics = step_fn(state, batch)
-        if mgr and (t + 1) % args.ckpt_every == 0:
-            mgr.save(t + 1, state)
-        if t % 5 == 0:
-            print(f"step {t:4d} loss {float(metrics['loss']):.4f} "
-                  f"({(time.perf_counter()-t0)*1e3:.0f} ms)")
-    if mgr:
-        mgr.wait()
+    sample = {k: jnp.asarray(v) for k, v in data.batch_at(start).items()}
+    step = compile_step(cfg, opt, state, axes, sample, mesh=mesh, comms=comms)
+    run_steps(step, state, data, start, args.steps, mgr=mgr,
+              ckpt_every=args.ckpt_every)
 
 
 if __name__ == "__main__":

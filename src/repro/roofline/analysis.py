@@ -21,23 +21,9 @@ __all__ = [
     "HW",
     "RooflineTerms",
     "collective_bytes_from_hlo",
-    "cost_analysis_dict",
     "roofline_terms",
     "model_flops",
 ]
-
-
-def cost_analysis_dict(compiled) -> Dict[str, Any]:
-    """``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a dict; newer jax returns a one-element list of dicts
-    (one per partition/program). Normalizes to the single dict every caller
-    wants (empty dict if the analysis is unavailable).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
 
 
 @dataclasses.dataclass(frozen=True)

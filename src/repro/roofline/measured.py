@@ -44,7 +44,6 @@ from repro.roofline.analysis import (
     HW,
     V5E,
     collective_bytes_from_hlo,
-    cost_analysis_dict,
     model_flops,
     roofline_terms,
 )
@@ -111,7 +110,7 @@ def _compile_cost(fn, args, in_shardings, mesh: Mesh, out_shardings=None):
             fn, in_shardings=in_shardings, out_shardings=out_shardings
         ).lower(*args)
         compiled = lowered.compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     return (
         float(cost.get("flops", 0.0)),

@@ -20,9 +20,15 @@ from typing import Any, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.sharding.rules import dp_axes, dp_size, spec_for, with_zero
+from repro.sharding.rules import auto_axes, dp_axes, dp_size, spec_for, with_zero
 
-__all__ = ["sharding_ctx", "ctx_axes", "constrain_layer_params", "constrain_activation"]
+__all__ = [
+    "sharding_ctx",
+    "ctx_axes",
+    "ctx_mesh",
+    "constrain_layer_params",
+    "constrain_activation",
+]
 
 _CTX: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
     "repro_sharding_ctx", default=None
@@ -35,11 +41,17 @@ _IS_AXES_LEAF = lambda a: isinstance(a, tuple) and all(isinstance(s, str) for s 
 def sharding_ctx(mesh: Mesh, axes=None, *, zero: bool = True):
     """``axes`` is the full model axes tree (from init_model); the model pulls
     per-unit sub-axes out of it when applying in-body constraints."""
-    token = _CTX.set({"mesh": mesh, "zero": zero, "axes": axes})
+    token = _CTX.set({"mesh": auto_axes(mesh), "zero": zero, "axes": axes})
     try:
         yield
     finally:
         _CTX.reset(token)
+
+
+def ctx_mesh() -> Optional[Mesh]:
+    """The active context's mesh (``Auto`` axes), or None outside one."""
+    ctx = _CTX.get()
+    return None if ctx is None else ctx["mesh"]
 
 
 def ctx_axes(section: str):

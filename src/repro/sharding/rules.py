@@ -16,10 +16,11 @@ from __future__ import annotations
 from typing import Dict, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "TP_RULES",
+    "auto_axes",
     "dp_axes",
     "spec_for",
     "sharding_for",
@@ -41,6 +42,20 @@ TP_RULES: Tuple[Tuple[str, str], ...] = (
 
 # Logical axes that must never be sharded (scan/layer dims, tiny dims).
 NEVER_SHARD = ("layers", "head_dim", "gates")
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes, and
+    ``with_sharding_constraint`` refuses specs over them.  The program
+    shards by constraint (GSPMD propagation), so each place that turns a
+    caller's mesh into a constraint goes through here: same devices, same
+    axis names, so the constrained layout is the one the caller named.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=(AxisType.Auto,) * mesh.devices.ndim)
 
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
@@ -122,4 +137,4 @@ def sharding_for(
     spec = spec_for(shape, axes, mesh)
     if zero:
         spec = with_zero(shape, spec, mesh)
-    return NamedSharding(mesh, spec)
+    return NamedSharding(auto_axes(mesh), spec)

@@ -17,7 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.optimizers.base import FactoredMoment
 from repro.core.quantizer import QuantizedTensor
-from repro.sharding.rules import dp_axes, dp_size, spec_for, with_zero
+from repro.sharding.rules import auto_axes, dp_axes, dp_size, spec_for, with_zero
 
 __all__ = [
     "param_shardings",
@@ -31,7 +31,7 @@ _IS_AXES_LEAF = lambda a: isinstance(a, tuple) and all(isinstance(s, str) for s 
 
 
 def replicated(mesh: Mesh) -> NamedSharding:
-    return NamedSharding(mesh, P())
+    return NamedSharding(auto_axes(mesh), P())
 
 
 def param_shardings(params, axes, mesh: Mesh, zero: bool = False):
@@ -40,6 +40,7 @@ def param_shardings(params, axes, mesh: Mesh, zero: bool = False):
     sharding: fp32 masters never exist replicated; compute all-gathers bf16
     casts on demand)."""
 
+    mesh = auto_axes(mesh)
     def one(p, a):
         spec = spec_for(tuple(p.shape), a, mesh)
         if zero:
@@ -123,6 +124,7 @@ def opt_state_shardings(opt_state, params, axes, mesh: Mesh, zero: bool = True):
     and other scalars are replicated; unrecognized leaves fall back to
     replicated.
     """
+    mesh = auto_axes(mesh)
     from repro.core.optimizers.transform import ChainState, MaskedNode, PartitionState
 
     treedef = jax.tree_util.tree_structure(params)
@@ -196,6 +198,7 @@ def opt_state_shardings(opt_state, params, axes, mesh: Mesh, zero: bool = True):
 
 def batch_shardings(batch, mesh: Mesh):
     """Shard the leading (batch) dim over pod×data when divisible."""
+    mesh = auto_axes(mesh)
     dps = dp_axes(mesh)
     n_dp = dp_size(mesh)
     dp_entry = dps if len(dps) > 1 else (dps[0] if dps else None)
@@ -223,6 +226,7 @@ def cache_shardings(caches, mesh: Mesh):
     (transient), so HBM residency stays sharded. When batch does not divide
     dp (long_500k batch=1), slots shard over 'data' as well (sequence
     parallelism)."""
+    mesh = auto_axes(mesh)
     dps = dp_axes(mesh)
     n_dp = dp_size(mesh)
     dp_entry = dps if len(dps) > 1 else (dps[0] if dps else None)

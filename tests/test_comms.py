@@ -25,9 +25,9 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.comms import (
@@ -253,7 +253,7 @@ def test_reduce_grads_bit_identical_across_mesh_layouts(mode):
 def _all_reduce_fn(mesh, qcfg, key):
     @functools.partial(
         shard_map, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-        check_rep=False,  # vmapped dequantize defeats replication inference
+        check_vma=False,  # vmapped dequantize defeats replication inference
     )
     def reduced(xs):
         return quantized_all_reduce(xs[0], qcfg, "data", key=key)[None]

@@ -1,4 +1,4 @@
-"""Single-launch 3-d-grid fused kernel: stacked-leaf bit-exactness + trace gates.
+"""Single-launch fused kernel: stacked-leaf bit-exactness + trace gates.
 
 Two invariants, both CI-enforced in the ``kernel-parity`` matrix:
 
@@ -210,7 +210,7 @@ def test_ref_backend_trace_is_depth_independent(monkeypatch):
 
 def test_4d_leaf_single_launch_and_bit_exact(monkeypatch):
     """ndim>3 stacked leaves (e.g. (G, L, R, C) grouped stacks) flatten their
-    leading dims into the one 3-d grid too."""
+    leading dims into the one launch's outer grid dim too."""
     monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
     m_cfg = dataclasses.replace(M_4BIT, stochastic_rounding=True)
     v_cfg = dataclasses.replace(V_4BIT, stochastic_rounding=True)
@@ -230,3 +230,37 @@ def test_4d_leaf_single_launch_and_bit_exact(monkeypatch):
     _assert_bits_equal(w_new, fw)
     _assert_bits_equal(m2.codes, fmp.reshape(m2.codes.shape))
     _assert_bits_equal(v2.codes, fvp.reshape(v2.codes.shape))
+
+
+# ---------------------------------------------------------------------------
+# mesh launches: one shard_map'd launch per device, bit-identical to one
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs the 8-device host harness")
+@pytest.mark.parametrize("use_sr", [False, True], ids=["rtn", "sr"])
+@pytest.mark.parametrize(
+    "L,R", [(2, 64), (8, 16), (3, 16)], ids=["split-rows", "split-slices", "whole"]
+)
+def test_mesh_launch_bit_identical_to_single_launch(monkeypatch, use_sr, L, R):
+    """Inside a train step's sharding context the kernel runs under
+    shard_map (XLA cannot partition a Mosaic kernel).  Whether each device
+    takes a share of the rows (row0 keeps the SR counters global), a share
+    of the slices, or the whole leaf, the outputs equal the one unsharded
+    launch bit for bit."""
+    from repro.launch.mesh import make_mesh
+    from repro.sharding.context import sharding_ctx
+
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "interpret")
+    p, g, m_q, v_q = _mk_leaf(L, R=R, sr=use_sr, seed=31)
+    key = jax.random.PRNGKey(13) if use_sr else None
+    mesh = make_mesh((2, 4), ("data", "model"))
+
+    def on_mesh(*args):
+        with sharding_ctx(mesh):
+            return _run_new_leaf(*args)
+
+    single = jax.jit(_run_new_leaf)(p, g, m_q, v_q, key)
+    sharded = jax.jit(on_mesh)(p, g, m_q, v_q, key)
+    for a, b in zip(jax.tree_util.tree_leaves(single), jax.tree_util.tree_leaves(sharded)):
+        _assert_bits_equal(a, b)

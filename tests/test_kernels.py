@@ -98,11 +98,9 @@ def test_fused_adamw4_matches_ref(shape):
         lr, hp["b1"], hp["b2"], hp["eps"], hp["weight_decay"], bc1, bc2,
     )
     # kernel (interpret mode executes the kernel body on CPU)
-    tile_r = 128 if R % 128 == 0 else 64
     w_k, mp_k, ms_k, vp_k = fused_adamw4(
         w, g, m_packed, m_scale, v_packed, v_r, v_c, vr_r, vc_r,
-        M_TABLE, V_TABLE, lr, bc1, bc2, interpret=True,
-        tile_r=tile_r, tile_c=min(512, C), **hp,
+        M_TABLE, V_TABLE, lr, bc1, bc2, interpret=True, tile_r=pick_r(R), **hp,
     )
     np.testing.assert_allclose(np.asarray(w_k), np.asarray(w_r), rtol=2e-6, atol=1e-7)
     np.testing.assert_array_equal(np.asarray(mp_k), np.asarray(mp_r))
@@ -183,7 +181,7 @@ def _sr_kernel_and_ref(shape, seed_words, base_seed=3):
     out_k = fused_adamw4(
         w, g, m_packed, m_scale, v_packed, v_r, v_c, vr_r, vc_r,
         M_TABLE, V_TABLE, lr, bc1, bc2, seed,
-        interpret=True, use_sr=True, tile_r=pick_r(R), tile_c=min(512, C), **hp,
+        interpret=True, use_sr=True, tile_r=pick_r(R), **hp,
     )
     return out_ref, out_k
 
@@ -251,7 +249,7 @@ def test_fused_adamw4_3d_grid_matches_per_slice_launches(use_sr):
 
 def test_sr_kernel_tiling_invariant():
     """The noise is keyed on global element indices, so retiling the kernel
-    must not change a single code (results independent of tile shape)."""
+    must not change a single code (results independent of rows per tile)."""
     shape = (128, 512)
     w = _rand(shape, seed=31)
     g = _rand(shape, seed=32, scale=0.1)
@@ -266,9 +264,9 @@ def test_sr_kernel_tiling_invariant():
         fused_adamw4(
             w, g, m_packed, m_scale, v_packed, v_r, v_c, vr_n, vc_n,
             M_TABLE, V_TABLE, lr, bc1, bc2, seed,
-            interpret=True, use_sr=True, tile_r=tr, tile_c=tc, **hp,
+            interpret=True, use_sr=True, tile_r=tr, **hp,
         )
-        for tr, tc in [(128, 512), (64, 256), (32, 512)]
+        for tr in (128, 64, 32, 8)
     ]
     for other in outs[1:]:
         for a, b in zip(outs[0], other):

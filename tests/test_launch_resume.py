@@ -68,7 +68,9 @@ def test_cli_train_checkpoint_resume(tmp_path):
     repo_root = str(pathlib.Path(__file__).resolve().parents[1])
     env = {"PYTHONPATH": str(pathlib.Path(repo_root) / "src"),
            "PATH": "/usr/bin:/bin:/usr/local/bin",
-           "JAX_PLATFORMS": "cpu"}
+           "JAX_PLATFORMS": "cpu",
+           # keep the CLI's compile cache out of the checkout
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")}
     r1 = subprocess.run(cmd + ["--steps", "4"], capture_output=True, text=True,
                         env=env, cwd=repo_root, timeout=420)
     assert r1.returncode == 0, r1.stderr[-2000:]

@@ -9,7 +9,6 @@ import pytest
 from repro.roofline.analysis import (
     _ring_bytes,
     collective_bytes_from_hlo,
-    cost_analysis_dict,
     roofline_terms,
 )
 
@@ -76,8 +75,8 @@ def test_xla_cost_analysis_undercounts_scans():
         return x
 
     x = jnp.zeros((64, 64))
-    fl_scan = cost_analysis_dict(jax.jit(f_scan).lower(x).compile())["flops"]
-    fl_unroll = cost_analysis_dict(jax.jit(f_unroll).lower(x).compile())["flops"]
+    fl_scan = jax.jit(f_scan).lower(x).compile().cost_analysis()["flops"]
+    fl_unroll = jax.jit(f_unroll).lower(x).compile().cost_analysis()["flops"]
     assert fl_unroll > 10 * fl_scan  # would be ~equal if scans were counted
 
 
@@ -132,8 +131,8 @@ def test_decomposed_cost_matches_unrolled_whole_model():
 
     g_whole = jax.jit(jax.grad(whole))
     g_manual = jax.jit(jax.grad(manual))
-    fl_scan = cost_analysis_dict(g_whole.lower(params).compile())["flops"]
-    fl_manual = cost_analysis_dict(g_manual.lower(params).compile())["flops"]
+    fl_scan = g_whole.lower(params).compile().cost_analysis()["flops"]
+    fl_manual = g_manual.lower(params).compile().cost_analysis()["flops"]
     # manual-unrolled counts every layer; the scanned module counts one body.
     # Reconstruct: scan_total ~= per_layer x L (+ tails)
     per_layer_upper = fl_scan  # scan module ~ 1 body + tails
