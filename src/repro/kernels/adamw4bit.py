@@ -381,6 +381,7 @@ def fused_adamw4(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
+        name="fused_adamw4",  # the trace's name for the kernel
     )(
         scalars,
         pad16(m_table),

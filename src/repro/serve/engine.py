@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import (
     ModelConfig,
     decode_step,
@@ -114,12 +115,14 @@ class ServeEngine:
 
         def _prefill(params, caches, tokens, lengths, admit,
                      kw, temp, topk, cur_tok, cur_pos, cur_gen):
-            p = materialize(params)
+            with jax.named_scope(obs.MATERIALIZE):
+                p = materialize(params)
             fresh = init_serve_cache(cfg, B, s_max)
             logits, fresh = prefill_with_cache(p, cfg, tokens, lengths, fresh)
-            first = sample_tokens(
-                logits, kw, jnp.zeros((B,), jnp.int32), temp, topk
-            )
+            with jax.named_scope(obs.SAMPLE):
+                first = sample_tokens(
+                    logits, kw, jnp.zeros((B,), jnp.int32), temp, topk
+                )
 
             def merge(new, old):
                 mask = admit.reshape((1, B) + (1,) * (new.ndim - 2))
@@ -132,12 +135,14 @@ class ServeEngine:
             return caches, tok, pos, gen, first
 
         def _decode(params, caches, tokens, pos, kw, gen, temp, topk):
-            p = materialize(params)
+            with jax.named_scope(obs.MATERIALIZE):
+                p = materialize(params)
 
             def body(carry, _):
                 caches, tok, pos, gi = carry
                 logits, caches = decode_step(p, cfg, caches, tok, pos)
-                nxt = sample_tokens(logits, kw, gi, temp, topk)
+                with jax.named_scope(obs.SAMPLE):
+                    nxt = sample_tokens(logits, kw, gi, temp, topk)
                 return (caches, nxt, pos + 1, gi + 1), nxt
 
             (caches, tok, pos, gen), toks = jax.lax.scan(
@@ -160,44 +165,49 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def _admit_and_prefill(self) -> List[int]:
         """Fill free slots from the queue; one batched prefill for all."""
-        admitted: List[int] = []
-        for slot in range(self.max_batch):
-            if self.active[slot] is None and self.queue:
-                req = self.queue.pop(0)
-                self.active[slot] = req
-                k0, k1 = request_key_words(self.seed, req.rid)
-                self.kw[slot] = (int(k0), int(k1))
-                self.temp[slot] = req.temperature
-                self.topk[slot] = req.top_k
-                admitted.append(slot)
-        if not admitted:
-            return admitted
+        with obs.span(obs.ADMIT) as span:
+            admitted: List[int] = []
+            for slot in range(self.max_batch):
+                if self.active[slot] is None and self.queue:
+                    req = self.queue.pop(0)
+                    self.active[slot] = req
+                    k0, k1 = request_key_words(self.seed, req.rid)
+                    self.kw[slot] = (int(k0), int(k1))
+                    self.temp[slot] = req.temperature
+                    self.topk[slot] = req.top_k
+                    admitted.append(slot)
+            span.set_metadata(admitted=len(admitted))
+            if not admitted:
+                return admitted
 
-        S = _bucket_len(max(len(self.active[s].prompt) for s in admitted))
-        toks = np.zeros((self.max_batch, S), np.int32)
-        lens = np.zeros((self.max_batch,), np.int32)
-        admit = np.zeros((self.max_batch,), bool)
-        for slot in admitted:
-            p = self.active[slot].prompt
-            toks[slot, : len(p)] = p
-            lens[slot] = len(p)
-            admit[slot] = True
+            S = _bucket_len(max(len(self.active[s].prompt) for s in admitted))
+            toks = np.zeros((self.max_batch, S), np.int32)
+            lens = np.zeros((self.max_batch,), np.int32)
+            admit = np.zeros((self.max_batch,), bool)
+            for slot in admitted:
+                p = self.active[slot].prompt
+                toks[slot, : len(p)] = p
+                lens[slot] = len(p)
+                admit[slot] = True
 
-        self.caches, tok, pos, gen, _ = self._prefill(
-            self.params, self.caches,
-            jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(admit),
-            jnp.asarray(self.kw), jnp.asarray(self.temp),
-            jnp.asarray(self.topk), jnp.asarray(self.tokens),
-            jnp.asarray(self.pos), jnp.asarray(self.gen_idx),
-        )
-        self.tokens = np.asarray(tok)
-        self.pos = np.asarray(pos)
-        self.gen_idx = np.asarray(gen)
+        # every row is computed at the bucket's width, admitted or not
+        with obs.span(obs.PREFILL, admitted=len(admitted),
+                      prompt_tokens=int(lens.sum()), positions=toks.size):
+            self.caches, tok, pos, gen, _ = self._prefill(
+                self.params, self.caches,
+                jnp.asarray(toks), jnp.asarray(lens), jnp.asarray(admit),
+                jnp.asarray(self.kw), jnp.asarray(self.temp),
+                jnp.asarray(self.topk), jnp.asarray(self.tokens),
+                jnp.asarray(self.pos), jnp.asarray(self.gen_idx),
+            )
+            self.tokens = np.asarray(tok)
+            self.pos = np.asarray(pos)
+            self.gen_idx = np.asarray(gen)
 
-        for slot in admitted:
-            req = self.active[slot]
-            req.output.append(int(self.tokens[slot]))
-            self._maybe_retire(slot)
+            for slot in admitted:
+                req = self.active[slot]
+                req.output.append(int(self.tokens[slot]))
+                self._maybe_retire(slot)
         return admitted
 
     def _maybe_retire(self, slot: int) -> None:
@@ -208,35 +218,55 @@ class ServeEngine:
             self.active[slot] = None  # slot backfills at the next tick
 
     # ------------------------------------------------------------------
-    def step(self) -> bool:
-        """One engine tick: admit+prefill, then decode ``drain_every``
-        tokens on device and drain them. Returns False when idle."""
-        self._admit_and_prefill()
-        if all(r is None for r in self.active):
-            return False
-
-        self.caches, tok, pos, gen, toks = self._decode(
+    def _decode_args(self) -> tuple:
+        return (
             self.params, self.caches,
             jnp.asarray(self.tokens), jnp.asarray(self.pos),
             jnp.asarray(self.kw), jnp.asarray(self.gen_idx),
             jnp.asarray(self.temp), jnp.asarray(self.topk),
         )
-        # ONE host sync per drain_every tokens: the (N, B) token block.
-        toks = np.asarray(toks)
-        self.tokens = np.asarray(tok)
-        self.pos = np.asarray(pos)
-        self.gen_idx = np.asarray(gen)
 
-        for slot in range(self.max_batch):
-            req = self.active[slot]
-            if req is None:
-                continue
-            for n in range(toks.shape[0]):
-                req.output.append(int(toks[n, slot]))
-                self._maybe_retire(slot)
-                if self.active[slot] is None:
-                    break  # chunk tokens past the end are discarded
-        return True
+    def decode_hlo(self) -> str:
+        """The optimized HLO text of the decode chunk, compiled for this
+        engine's shapes (read back from the compile cache when it is on)."""
+        return self._decode.lower(*self._decode_args()).compile().as_text()
+
+    def step(self) -> bool:
+        """One engine tick: admit+prefill, then decode ``drain_every``
+        tokens on device and drain them. Returns False when idle."""
+        with obs.span(obs.STEP):
+            self._admit_and_prefill()
+            if all(r is None for r in self.active):
+                return False
+
+            with obs.span(obs.DECODE):
+                self.caches, tok, pos, gen, toks = self._decode(*self._decode_args())
+            # ONE host sync per drain_every tokens: the (N, B) token block.
+            with obs.span(obs.DRAIN):
+                toks = np.asarray(toks)
+                self.tokens = np.asarray(tok)
+                self.pos = np.asarray(pos)
+                self.gen_idx = np.asarray(gen)
+
+            with obs.span(obs.RETIRE) as span:
+                n_tok = toks.shape[0]
+                delivered = discarded = retired = 0
+                for slot in range(self.max_batch):
+                    req = self.active[slot]
+                    if req is None:
+                        continue
+                    for n in range(n_tok):
+                        req.output.append(int(toks[n, slot]))
+                        delivered += 1
+                        self._maybe_retire(slot)
+                        if self.active[slot] is None:
+                            # chunk tokens past the end are discarded
+                            retired += 1
+                            discarded += n_tok - 1 - n
+                            break
+                span.set_metadata(delivered=delivered, discarded=discarded,
+                                  retired=retired)
+            return True
 
     def run(self, max_ticks: int = 10_000) -> None:
         for _ in range(max_ticks):

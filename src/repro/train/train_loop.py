@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
+from repro import obs
 from repro.comms import CommsConfig, grad_comm_key, reduce_grads
 from repro.core.optimizers.base import Optimizer
 from repro.core.optimizers.transform import GradientTransformation, as_optimizer
@@ -119,9 +120,14 @@ def build_train_step(
     optimizer = _coerce_optimizer(optimizer)
     comms = comms if comms is not None else CommsConfig()
 
+    def fwd(p, batch):
+        with jax.named_scope(obs.FWD):
+            return loss_fn(p, cfg, batch)
+
     def compute_grads(params, batch):
+        # backward ops carry ``transpose(jvp(fwd))``, remat's recompute too
         (loss, metrics), grads = jax.value_and_grad(
-            lambda p: loss_fn(p, cfg, batch), has_aux=True
+            lambda p: fwd(p, batch), has_aux=True
         )(params)
         return loss, metrics, grads
 
@@ -190,16 +196,18 @@ def build_train_step(
                 comms_mesh, comms, key=ck,
             )
 
-        if state.key is not None:
-            # Per-step SR key: a pure function of (base key, step) so a
-            # restored run re-derives the identical key stream.  compressed()
-            # folds in the leaf index (and splits per moment) downstream.
-            step_key = jax.random.fold_in(state.key, state.step)
-            new_params, new_opt = optimizer.update(
-                grads, state.opt_state, params, key=step_key
-            )
-        else:
-            new_params, new_opt = optimizer.update(grads, state.opt_state, params)
+        with jax.named_scope(obs.OPTIMIZER):
+            if state.key is not None:
+                # Per-step SR key: a pure function of (base key, step) so a
+                # restored run re-derives the identical key stream.
+                # compressed() folds in the leaf index (and splits per
+                # moment) downstream.
+                step_key = jax.random.fold_in(state.key, state.step)
+                new_params, new_opt = optimizer.update(
+                    grads, state.opt_state, params, key=step_key
+                )
+            else:
+                new_params, new_opt = optimizer.update(grads, state.opt_state, params)
         metrics = dict(metrics)
         metrics["loss"] = loss
         metrics["grad_norm"] = jnp.sqrt(

@@ -16,6 +16,7 @@ imports every test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -81,3 +82,18 @@ def test_fused_adamw4_compiles_for_v5e(one_chip, shape, use_sr):
     )
     compiled = step.lower(*_operands(shape, use_sr, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_adamw4_keeps_its_trace_name(one_chip):
+    """The kernel's custom call is named ``fused_adamw4`` (``.N``), the name
+    ``bench/trace.py`` reads its device time by, whatever Python function
+    wraps it: here its body called from a function of another name."""
+    body = fused_adamw4.__wrapped__
+
+    def update_leaf(*a):
+        return body(*a, use_sr=False, **HP)
+
+    text = jax.jit(update_leaf).lower(*_operands((256, 512), False, one_chip)).compile().as_text()
+    calls = [line.split(" = ")[0].split()[-1].lstrip("%") for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(re.fullmatch(r"fused_adamw4(\.\d+)?", c) for c in calls), calls
