@@ -8,7 +8,9 @@ Two entry points:
   are spent on fully-masked blocks and the S×S logit matrix never
   materializes (required for the 32k shapes).
 * ``decode_attention`` — one query step against a (possibly circular) KV
-  cache, scanning k chunks with dynamic position masks.
+  cache, scanning k chunks with dynamic position masks.  On the serving
+  path the cache is a scan unit's whole stack, read and written in place
+  at a layer index (``cache_update`` scatters only the new token).
 
 GQA: q heads are grouped per kv head; kv heads are never replicated in
 memory — the grouping happens in the einsum index structure.
@@ -31,9 +33,9 @@ class KVCache(NamedTuple):
     """Circular KV cache. ``pos`` holds the absolute position stored in each
     slot (-1 = empty). Windowed layers allocate only ``window`` slots."""
 
-    k: jnp.ndarray    # (B, Smax, Hkv, D)
-    v: jnp.ndarray    # (B, Smax, Hkv, D)
-    pos: jnp.ndarray  # (B, Smax) int32, absolute positions, -1 empty
+    k: jnp.ndarray    # (B, Smax, Hkv, D), or (L, B, Smax, Hkv, D) stacked
+    v: jnp.ndarray    # (B, Smax, Hkv, D), or (L, B, Smax, Hkv, D) stacked
+    pos: jnp.ndarray  # (B, Smax) or (L, B, Smax) int32, absolute positions, -1 empty
 
 
 def _split_heads(q, n_kv):
@@ -158,13 +160,23 @@ def decode_attention(
     cache: KVCache,
     cur_pos: jnp.ndarray,  # (B,) absolute position of the query token
     *,
+    layer=None,            # index into a stacked (L, B, Smax, ...) cache
     window: int = 0,
     softcap_val: float = 0.0,
     k_chunk: int = 1024,
     unroll: bool = False,
 ) -> jnp.ndarray:
+    """Attend one query step over a layer's cache.
+
+    With ``layer`` given, ``cache`` is the whole stack of a scan unit and
+    each k chunk is sliced straight out of it at ``(layer, :, chunk)``: the
+    read fuses into the attention and no copy of the layer is ever made.
+    Without it, ``cache`` is one layer's.
+    """
+    if layer is None:
+        cache, layer = _stacked(cache), 0
     B, _, Hq, D = q.shape
-    Smax, Hkv = cache.k.shape[1], cache.k.shape[2]
+    Smax, Hkv = cache.k.shape[2], cache.k.shape[3]
     G = Hq // Hkv
     scale = 1.0 / math.sqrt(D)
     kc = min(k_chunk, Smax)
@@ -173,11 +185,13 @@ def decode_attention(
 
     qg = _split_heads(q, Hkv)[:, 0]  # (B, Hkv, G, D)
 
+    def chunk(a, jk):
+        start = (layer, 0, jk * kc) + (0,) * (a.ndim - 3)
+        return jax.lax.dynamic_slice(a, start, (1, B, kc) + a.shape[3:])[0]
+
     def body(carry, jk):
         m, l, acc = carry
-        ks = jax.lax.dynamic_slice_in_dim(cache.k, jk * kc, kc, axis=1)
-        vs = jax.lax.dynamic_slice_in_dim(cache.v, jk * kc, kc, axis=1)
-        ps = jax.lax.dynamic_slice_in_dim(cache.pos, jk * kc, kc, axis=1)
+        ks, vs, ps = chunk(cache.k, jk), chunk(cache.v, jk), chunk(cache.pos, jk)
 
         s = jnp.einsum(
             "bhgd,bkhd->bhgk", qg.astype(jnp.float32), ks.astype(jnp.float32)
@@ -247,15 +261,33 @@ def cache_prefill(
     return KVCache(k, v, p)
 
 
-def cache_update(cache: KVCache, k_new: jnp.ndarray, v_new: jnp.ndarray, pos: jnp.ndarray) -> KVCache:
-    """Write one decode step into the circular cache. pos: (B,)."""
-    Smax = cache.k.shape[1]
+def cache_update(cache: KVCache, k_new: jnp.ndarray, v_new: jnp.ndarray,
+                 pos: jnp.ndarray, layer=None) -> KVCache:
+    """Write one decode step into the circular cache. pos: (B,).
+
+    With ``layer`` given, ``cache`` is the whole stack of a scan unit and
+    only the new token's K, V and position are scattered, at
+    ``(layer, b, pos % Smax)``: the stack is updated in place.  Without it,
+    ``cache`` is one layer's.
+    """
+    if layer is None:
+        return _unstacked(cache_update(_stacked(cache), k_new, v_new, pos, 0))
+    B, Smax = cache.k.shape[1], cache.k.shape[2]
     slot = (pos % Smax).astype(jnp.int32)  # (B,)
-    b_idx = jnp.arange(cache.k.shape[0])
-    k = cache.k.at[b_idx, slot].set(k_new[:, 0].astype(cache.k.dtype))
-    v = cache.v.at[b_idx, slot].set(v_new[:, 0].astype(cache.v.dtype))
-    p = cache.pos.at[b_idx, slot].set(pos.astype(jnp.int32))
+    b_idx = jnp.arange(B)
+    k = cache.k.at[layer, b_idx, slot].set(k_new[:, 0].astype(cache.k.dtype))
+    v = cache.v.at[layer, b_idx, slot].set(v_new[:, 0].astype(cache.v.dtype))
+    p = cache.pos.at[layer, b_idx, slot].set(pos.astype(jnp.int32))
     return KVCache(k, v, p)
+
+
+def _stacked(cache: KVCache) -> KVCache:
+    """One layer's cache as a stack of one."""
+    return KVCache(*(a[None] for a in cache))
+
+
+def _unstacked(cache: KVCache) -> KVCache:
+    return KVCache(*(a[0] for a in cache))
 
 
 def make_cache(batch: int, s_max: int, n_kv: int, head_dim: int, dtype=jnp.bfloat16) -> KVCache:

@@ -105,11 +105,14 @@ def apply_attention(
     cache: Optional[KVCache] = None,
     cur_pos: Optional[jnp.ndarray] = None,    # (B,) decode position
     kv_lengths: Optional[jnp.ndarray] = None,  # (B,) prefill prompt lengths
+    layer=None,               # decode: ``cache`` is a stack, this its layer
 ):
     """Returns (out, new_cache).
 
     Three cache regimes: ``cache + cur_pos`` with a single-token input is a
-    decode step (circular write + position-masked attention);
+    decode step (circular write + position-masked attention; with ``layer``
+    the cache is the scan unit's whole stack and is returned updated in
+    place at that layer);
     ``cache + kv_lengths`` with a full sequence is a one-shot prefill (the
     forward runs as train attention and the whole K/V sequence is written
     into the cache in one gather); cache-less calls are plain training.
@@ -138,16 +141,16 @@ def apply_attention(
     new_cache = cache
     if decode:
         if kv_source is None:
-            new_cache = attn_lib.cache_update(cache, k, v, cur_pos)
+            new_cache = attn_lib.cache_update(cache, k, v, cur_pos, layer)
             out = attn_lib.decode_attention(
-                q, new_cache, cur_pos, window=window,
+                q, new_cache, cur_pos, layer=layer, window=window,
                 softcap_val=cfg.attn_softcap, k_chunk=cfg.decode_k_chunk,
                 unroll=cfg.unroll_scans,
             )
         else:
             # cross-attention: cache holds the full encoder K/V (always valid)
             out = attn_lib.decode_attention(
-                q, cache, jnp.full_like(cur_pos, 2**30), window=0,
+                q, cache, jnp.full_like(cur_pos, 2**30), layer=layer, window=0,
                 softcap_val=cfg.attn_softcap, k_chunk=cfg.decode_k_chunk,
                 unroll=cfg.unroll_scans,
             )
@@ -215,11 +218,11 @@ def _init_dense(key, cfg):
 
 
 def _apply_dense(p, x, spec, cfg, *, positions, cache, cur_pos, enc_out=None,
-                 kv_lengths=None):
+                 kv_lengths=None, layer=None):
     h, new_cache = apply_attention(
         p["attn"], _norm_apply(cfg, x, p["norm1"]), cfg,
         window=spec.window, positions=positions, cache=cache, cur_pos=cur_pos,
-        kv_lengths=kv_lengths,
+        kv_lengths=kv_lengths, layer=layer,
     )
     if cfg.sandwich_norm:
         h = _norm_apply(cfg, h, p["post1"])
@@ -243,11 +246,11 @@ def _init_moe(key, cfg):
 
 
 def _apply_moe(p, x, spec, cfg, *, positions, cache, cur_pos, enc_out=None,
-               kv_lengths=None):
+               kv_lengths=None, layer=None):
     h, new_cache = apply_attention(
         p["attn"], _norm_apply(cfg, x, p["norm1"]), cfg,
         window=spec.window, positions=positions, cache=cache, cur_pos=cur_pos,
-        kv_lengths=kv_lengths,
+        kv_lengths=kv_lengths, layer=layer,
     )
     x = x + h
     out, aux = moe_apply(
@@ -289,7 +292,7 @@ def _init_mlstm(key, cfg):
 
 
 def _apply_mlstm(p, x, spec, cfg, *, positions, cache, cur_pos, enc_out=None,
-                 kv_lengths=None):
+                 kv_lengths=None, layer=None):
     cd = COMPUTE_DTYPE
     D = cfg.d_model
     H, dh = cfg.num_heads, D // cfg.num_heads
@@ -355,7 +358,7 @@ def _init_slstm(key, cfg):
 
 
 def _apply_slstm(p, x, spec, cfg, *, positions, cache, cur_pos, enc_out=None,
-                 kv_lengths=None):
+                 kv_lengths=None, layer=None):
     cd = COMPUTE_DTYPE
     h = _norm_apply(cfg, x, p["norm1"])
     gates_x = jnp.einsum("bsd,dge->bsge", h.astype(cd), p["w_gates"].astype(cd))
@@ -418,7 +421,7 @@ def _init_hymba(key, cfg):
 
 
 def _apply_hymba(p, x, spec, cfg, *, positions, cache, cur_pos, enc_out=None,
-                 kv_lengths=None):
+                 kv_lengths=None, layer=None):
     """Parallel attention + Mamba/SSD heads, outputs averaged (Hymba)."""
     cd = COMPUTE_DTYPE
     D, H = cfg.d_model, cfg.num_heads
@@ -428,7 +431,7 @@ def _apply_hymba(p, x, spec, cfg, *, positions, cache, cur_pos, enc_out=None,
 
     a_out, new_kv = apply_attention(
         p["attn"], h, cfg, window=spec.window, positions=positions,
-        cache=cache["attn"], cur_pos=cur_pos, kv_lengths=kv_lengths,
+        cache=cache["attn"], cur_pos=cur_pos, kv_lengths=kv_lengths, layer=layer,
     )
 
     up = jnp.einsum("bsd,de->bse", h.astype(cd), p["ssm_in"].astype(cd))
@@ -480,7 +483,7 @@ def _init_enc(key, cfg):
 
 
 def _apply_enc(p, x, spec, cfg, *, positions, cache, cur_pos, enc_out=None,
-               kv_lengths=None):
+               kv_lengths=None, layer=None):
     h, _ = apply_attention(
         p["attn"], _norm_apply(cfg, x, p["norm1"]), cfg,
         window=0, causal=False, positions=None,
@@ -507,18 +510,18 @@ def _init_dec(key, cfg):
 
 
 def _apply_dec(p, x, spec, cfg, *, positions, cache, cur_pos, enc_out=None,
-               kv_lengths=None):
+               kv_lengths=None, layer=None):
     cache = cache or {"self": None, "cross": None}
     h, new_self = apply_attention(
         p["self"], _norm_apply(cfg, x, p["norm1"]), cfg,
         window=spec.window, positions=None, cache=cache["self"], cur_pos=cur_pos,
-        kv_lengths=kv_lengths,
+        kv_lengths=kv_lengths, layer=layer,
     )
     x = x + h
     h, _ = apply_attention(
         p["cross"], _norm_apply(cfg, x, p["norm2"]), cfg,
         window=0, causal=False, positions=None,
-        kv_source=enc_out, cache=cache["cross"], cur_pos=cur_pos,
+        kv_source=enc_out, cache=cache["cross"], cur_pos=cur_pos, layer=layer,
     )
     x = x + h
     x = x + apply_mlp(p["mlp"], _norm_apply(cfg, x, p["norm3"]), act="gelu")

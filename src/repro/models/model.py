@@ -206,6 +206,27 @@ def _head_weight(cfg, params):
     return params["embed"].T if cfg.tie_embeddings else params["head"]
 
 
+def _layer_view(c_unit, li):
+    """Layer ``li``'s decode cache in a unit's stacked cache: KV caches stay
+    whole (attention reads and writes them at ``li``); recurrent states,
+    small per layer, are sliced out."""
+    return jax.tree_util.tree_map(
+        lambda c: c if isinstance(c, KVCache) else jax.lax.dynamic_index_in_dim(
+            c, li, keepdims=False),
+        c_unit, is_leaf=lambda c: isinstance(c, KVCache),
+    )
+
+
+def _layer_write(c_unit, new, li):
+    """Inverse of ``_layer_view``: KV stacks come back already updated;
+    recurrent states are written into the stack at ``li``."""
+    return jax.tree_util.tree_map(
+        lambda c, n: n if isinstance(c, KVCache) else jax.lax.dynamic_update_index_in_dim(
+            c, n, li, 0),
+        c_unit, new, is_leaf=lambda c: isinstance(c, KVCache),
+    )
+
+
 def _run_units(
     cfg: ModelConfig,
     units: List[ScanUnit],
@@ -217,24 +238,27 @@ def _run_units(
     caches: Optional[List[Any]] = None,
     cur_pos=None,
     kv_lengths=None,
-    collect_cache: bool = False,
     unit_axes: Optional[List[Any]] = None,
 ):
-    """Run all scan units. Returns (x, new_caches, aux_sum)."""
+    """Run all scan units. Returns (x, new_caches, aux_sum).
+
+    A decode call (``caches`` and ``cur_pos``) carries each unit's stacked
+    cache through the layer scan with the layer index, so every layer
+    writes its one new token into the stack in place; training and prefill
+    pass caches as the scan's ``xs``/``ys``.
+    """
     from repro.sharding.context import constrain_activation, constrain_layer_params
 
     aux_total = jnp.float32(0.0)
     new_caches: List[Any] = []
+    decode = caches is not None and cur_pos is not None
 
     for ui, unit in enumerate(units):
         p_unit = unit_params[ui]
         cache_unit = caches[ui] if caches is not None else None
         a_unit = unit_axes[ui] if unit_axes is not None else None
 
-        def body(carry, xs, _unit=unit, _axes=a_unit):
-            h, aux = carry
-            p_l = xs["params"]
-            c_l = xs.get("cache")
+        def apply_layer(h, aux, p_l, c_l, li, _unit=unit, _axes=a_unit):
             new_c = {}
             for si, spec in enumerate(_unit.pattern):
                 sub_cache = c_l[f"sub{si}"] if c_l is not None else None
@@ -247,20 +271,36 @@ def _run_units(
                 h, nc, a = apply_block(
                     p_sub, h, spec, cfg,
                     positions=positions, cache=sub_cache, cur_pos=cur_pos,
-                    enc_out=enc_out, kv_lengths=kv_lengths,
+                    enc_out=enc_out, kv_lengths=kv_lengths, layer=li,
                 )
                 new_c[f"sub{si}"] = nc
                 aux = aux + a
-            out = new_c if (c_l is not None or collect_cache) else None
-            return (h, aux), out
+            return h, aux, new_c
+
+        if decode:
+            def body(carry, xs):
+                h, aux, c_unit, li = carry
+                h, aux, new_c = apply_layer(h, aux, xs["params"], _layer_view(c_unit, li), li)
+                return (h, aux, _layer_write(c_unit, new_c, li), li + 1), None
+        else:
+            def body(carry, xs):
+                h, aux = carry
+                c_l = xs.get("cache")
+                h, aux, new_c = apply_layer(h, aux, xs["params"], c_l, None)
+                return (h, aux), (new_c if c_l is not None else None)
 
         if cfg.remat:
             body = jax.checkpoint(body)
 
         xs = {"params": p_unit}
-        if cache_unit is not None:
-            xs["cache"] = cache_unit
-        (x, aux_total), cache_out = jax.lax.scan(body, (x, aux_total), xs)
+        if decode:
+            (x, aux_total, cache_out, _), _ = jax.lax.scan(
+                body, (x, aux_total, cache_unit, jnp.int32(0)), xs
+            )
+        else:
+            if cache_unit is not None:
+                xs["cache"] = cache_unit
+            (x, aux_total), cache_out = jax.lax.scan(body, (x, aux_total), xs)
         new_caches.append(cache_out)
     return x, new_caches, aux_total
 
@@ -324,7 +364,8 @@ def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray]):
 
 
 def init_serve_cache(cfg: ModelConfig, batch: int, s_max: int):
-    """Cache pytree for decode. Stacked per scan unit (matches lax.scan xs)."""
+    """Cache pytree for decode, stacked per scan unit: each leaf leads with
+    the unit's ``repeat`` layers (decode updates the stacks in place)."""
     units = plan_scan_units(cfg.blocks)
     caches = []
     for unit in units:

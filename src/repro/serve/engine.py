@@ -12,6 +12,8 @@ admit → prefill → decode → retire loop:
   cannot survive. The first token of each stream is sampled from the
   prefill logits on device.
 * **decode** — a jitted ``lax.scan`` over ``drain_every`` decode steps.
+  The caches are donated to it and stay in one buffer: each step writes
+  only the new token's K, V and position into every layer's stack.
   Sampling (temperature / top-k, Gumbel-max) happens on device with
   counter-based Threefry streams keyed by (engine seed, request id), so the
   host syncs ONCE per ``drain_every`` tokens (one small (N, B) transfer)
@@ -151,7 +153,10 @@ class ServeEngine:
             return caches, tok, pos, gen, toks  # toks: (N, B)
 
         self._prefill = jax.jit(_prefill)
-        self._decode = jax.jit(_decode)
+        # the caches are donated: the chunk updates them in place, and the
+        # arrays passed in are deleted once it is dispatched (``self.caches``
+        # is always replaced by the result)
+        self._decode = jax.jit(_decode, donate_argnums=(1,))
 
     # ------------------------------------------------------------------
     def submit(self, req: Request):

@@ -18,6 +18,7 @@ from repro.models import (
     decode_step,
     init_model,
     init_serve_cache,
+    plan_scan_units,
     prefill_with_cache,
 )
 from repro.models.attention import cache_prefill, cache_update, make_cache
@@ -158,6 +159,122 @@ def test_cache_prefill_matches_sequential_writes():
     np.testing.assert_allclose(
         np.asarray(bulk.v, np.float32), np.asarray(seq.v, np.float32)
     )
+
+
+# ---------------------------------------------------------------------------
+# in-place decode vs a per-layer reference
+# ---------------------------------------------------------------------------
+
+
+def _per_layer_decode(params, cfg, caches, tokens, pos):
+    """Reference decode step with the per-layer data flow: every layer's
+    cache is sliced out of its unit's stack, run through its block as a
+    single layer's cache, and stacked back (rope archs)."""
+    from repro.models.blocks import apply_block
+    from repro.models.layers import embed_lookup, softcap
+    from repro.models.model import _final_norm, _head_weight
+
+    assert cfg.rope_variant == "rope"
+    h = embed_lookup(params["embed"], tokens[:, None])
+    new_caches = []
+    for unit, p_unit, c_unit in zip(plan_scan_units(cfg.blocks), params["decoder"], caches):
+        per_layer = []
+        for li in range(unit.repeat):
+            p_l = jax.tree_util.tree_map(lambda a: a[li], p_unit)
+            c_l = jax.tree_util.tree_map(lambda a: a[li], c_unit)
+            new_l = {}
+            for si, spec in enumerate(unit.pattern):
+                h, new_l[f"sub{si}"], _ = apply_block(
+                    p_l[f"sub{si}"], h, spec, cfg, positions=pos[:, None],
+                    cache=c_l[f"sub{si}"], cur_pos=pos,
+                )
+            per_layer.append(new_l)
+        new_caches.append(jax.tree_util.tree_map(lambda *a: jnp.stack(a), *per_layer))
+    h = _final_norm(cfg, h, params["final_norm"])
+    logits = jnp.einsum(
+        "bsd,dv->bsv", h.astype(jnp.bfloat16),
+        _head_weight(cfg, params).astype(jnp.bfloat16),
+    )[:, 0].astype(jnp.float32)
+    if cfg.final_softcap > 0:
+        logits = softcap(logits, cfg.final_softcap)
+    return logits, new_caches
+
+
+def _assert_caches_close(got, want, rel=2e-2):
+    """Same tree, shapes and dtypes; positions equal; every float leaf within
+    ``rel`` of its own largest magnitude (recurrent states are small)."""
+    g_leaves, g_tree = jax.tree_util.tree_flatten(got)
+    w_leaves, w_tree = jax.tree_util.tree_flatten(want)
+    assert g_tree == w_tree
+    for g, w in zip(g_leaves, w_leaves):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        exact = jnp.issubdtype(g.dtype, jnp.integer)
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if exact or not np.any(w):
+            np.testing.assert_array_equal(g, w)
+        else:
+            np.testing.assert_allclose(g, w, atol=rel * np.abs(w).max(), rtol=0)
+
+
+@pytest.mark.parametrize(
+    "arch", ["tiny", "gemma2-2b", "xlstm-125m", "hymba-1.5b"]
+)
+def test_inplace_decode_matches_per_layer_reference(arch, tiny_params):
+    # decode_step writes each step's token into the stacked caches in place;
+    # from a prefilled state, its logits and every layer's K/V/pos (and
+    # recurrent states) must match the per-layer data flow step by step,
+    # for full, windowed, recurrent and hybrid caches.
+    if arch == "tiny":
+        cfg, params, atol = TINY, tiny_params, 2e-2
+    else:
+        cfg = reduced_config(arch)
+        params, _ = init_model(jax.random.PRNGKey(0), cfg)
+        atol = 5e-2
+    prompts = [[5, 6, 7, 8, 9], [10, 11, 12], [13]]
+    _, caches = _batched_prefill(params, cfg, prompts, s_max=256)
+    ref_caches = caches
+    pos = jnp.array([len(p) for p in prompts], jnp.int32)
+    tok = jnp.array([3, 4, 5], jnp.int32)
+    for t in range(4):
+        logits, caches = decode_step(params, cfg, caches, tok, pos + t)
+        ref_logits, ref_caches = _per_layer_decode(params, cfg, ref_caches, tok, pos + t)
+        np.testing.assert_allclose(
+            np.asarray(logits), np.asarray(ref_logits), atol=atol, rtol=0
+        )
+        _assert_caches_close(caches, ref_caches)
+        tok = jnp.argmax(ref_logits, -1).astype(jnp.int32)
+
+
+def test_engine_donates_caches_and_keeps_greedy_streams(tiny_params):
+    # The decode chunk takes the engine's caches as donated buffers (they
+    # are dead after the call), and greedy streams equal prefill followed
+    # by the per-layer reference decode.
+    prompts = [[5, 6, 7, 8, 9], [10, 11, 12], [13]]
+    n_new = 9
+    eng = ServeEngine(TINY, tiny_params, max_batch=3, s_max=64, drain_every=4)
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=n_new) for i, p in enumerate(prompts)]
+    for r in reqs:
+        eng.submit(r)
+    assert eng.step()  # admit + prefill + one chunk
+    passed = jax.tree_util.tree_leaves(eng.caches)
+    assert passed and not any(a.is_deleted() for a in passed)
+    assert eng.step()  # one more chunk, nothing to admit
+    assert all(a.is_deleted() for a in passed)
+    eng.run()
+    assert all(r.done for r in reqs)
+
+    params = materialize(prepare_params(tiny_params, "bf16"))
+    logits, caches = _batched_prefill(params, TINY, prompts, s_max=64)
+    tok = jnp.asarray(np.argmax(logits, -1).astype(np.int32))
+    pos = jnp.array([len(p) for p in prompts], jnp.int32)
+    want = [tok]
+    for t in range(n_new - 1):
+        logits, caches = _per_layer_decode(params, TINY, caches, tok, pos + t)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        want.append(tok)
+    want = np.stack([np.asarray(w) for w in want], axis=1)
+    for b, r in enumerate(reqs):
+        assert r.output == want[b].tolist(), f"rid={b}"
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""The fused 4-bit AdamW kernel compiles for a TPU v5e at real widths.
+"""The fused 4-bit AdamW kernel and the serving decode chunk compile for a
+TPU v5e at real widths.
 
 Nothing here runs: the TPU compiler, which ships with jax's TPU plugin,
 compiles for a v5e that is described, not attached.  That catches what
@@ -8,7 +9,8 @@ time is spent.  Shapes are internlm2-1.8b MLP leaves — one ``(2048, 8192)``
 matrix and an 8-deep stacked ``(8, 2048, 8192)`` leaf (the grid's outer
 slice dim with L > 1) — and mixtral-8x7b's stacked expert leaf
 ``(8, 4096, 14336)``, whose wider rows leave the minimum 8 rows per tile;
-each with round-to-nearest and stochastic rounding.
+each with round-to-nearest and stochastic rounding.  The decode chunk is
+read for what it does with its KV cache: written in place, never copied.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every test worker
@@ -97,3 +99,76 @@ def test_fused_adamw4_keeps_its_trace_name(one_chip):
     calls = [line.split(" = ")[0].split()[-1].lstrip("%") for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert calls and all(re.fullmatch(r"fused_adamw4(\.\d+)?", c) for c in calls), calls
+
+
+def _hlo_instructions(text):
+    """(computation roots, instructions) of an optimized HLO module's text:
+    ``roots`` maps each computation to its ROOT opcode; each instruction is
+    ``(dtype, dims, opcode, called computation or None)`` for array-shaped
+    results (tuples are left out: they only pass buffers on)."""
+    roots, instrs, comp = {}, [], None
+    head = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+    instr = re.compile(r"^\s*(ROOT )?%\S+ = (\w+)\[([\d,]*)\]\{[^}]*\} ([\w-]+)\((.*)$")
+    for line in text.splitlines():
+        m = head.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = instr.match(line)
+        if not m:
+            continue
+        root, dtype, dims, opcode, rest = m.groups()
+        if root:
+            roots[comp] = opcode
+        called = re.search(r"calls=%([\w.-]+)", rest)
+        instrs.append((dtype, tuple(int(d) for d in dims.split(",") if d), opcode,
+                       called.group(1) if called else None))
+    return roots, instrs
+
+
+def test_decode_chunk_keeps_the_kv_cache_in_place_on_v5e(one_chip):
+    """``ServeEngine``'s decode chunk at internlm2-1.8b's published widths
+    (2 layers), 32 slots of 2048 positions, bf16 weights, compiled for a
+    described v5e: no instruction makes a layer's or the stack's K, V or
+    position cache apart from the in-place scatter of the new token, the
+    temporaries stay under one K stack, and both caches alias the output
+    (they are donated)."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import init_model
+    from repro.serve import ServeEngine
+
+    base = get_config("internlm2-1.8b")
+    L, B, S = 2, 32, 2048
+    cfg = dataclasses.replace(base, num_layers=L, blocks=base.blocks[:L])
+    built = {}
+
+    def build():
+        params = init_model(jax.random.PRNGKey(0), cfg)[0]
+        built["engine"] = ServeEngine(cfg, params, max_batch=B, s_max=S,
+                                      weights="bf16", drain_every=8)
+        return built["engine"]._decode_args()
+
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(build),
+    )
+    compiled = built["engine"]._decode.lower(*args).compile()
+
+    kv = (L, B, S, cfg.num_kv_heads, cfg.head_dim)
+    cache_shapes = {("bf16", kv), ("bf16", kv[1:]), ("s32", kv[:3]), ("s32", kv[1:3])}
+    roots, instrs = _hlo_instructions(compiled.as_text())
+    made = [
+        (opcode, called) for dtype, dims, opcode, called in instrs
+        if (dtype, dims) in cache_shapes
+        and opcode not in ("parameter", "get-tuple-element", "bitcast", "scatter")
+        and not (opcode == "fusion" and roots.get(called) == "scatter")
+    ]
+    assert not made, made
+
+    mem = compiled.memory_analysis()
+    k_stack = L * B * S * cfg.num_kv_heads * cfg.head_dim * 2
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(args[1]))
+    assert mem.temp_size_in_bytes < k_stack, mem
+    assert mem.alias_size_in_bytes >= cache_bytes, mem
